@@ -29,7 +29,7 @@ the federated ladder, with paged chunked prefill active.
 
 ``kv_attn_gathered`` vs ``kv_attn_paged_kernel`` drive the same stream
 with the only difference being how attention reads the page pool: the
-dense ``_paged_view`` copy vs the in-place ``kernels/paged_attention``
+dense ``paged_gather_view`` copy vs the in-place ``kernels/paged_attention``
 op.  Each row reports steps/s and the modeled per-layer attention HBM
 bytes/step (``attention_kv_bytes_per_step`` over the observed per-step
 row fills); ``kv_attn_accept`` asserts the kernel row moves strictly

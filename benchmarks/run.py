@@ -23,6 +23,7 @@ def main(argv=None) -> None:
                             cooperative_hit_rate, federated_hit_rate,
                             frame_deadline, hit_rate, kv_reuse, load_latency,
                             obs_overhead, recognition_latency, roofline)
+    from repro.launch.compile_cache import enable_compile_cache
 
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--trace-out", default="",
@@ -32,6 +33,7 @@ def main(argv=None) -> None:
                     help="export the obs_overhead traced run's metrics "
                          "registry snapshot here")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     suites = [
         ("fig2a", recognition_latency.run),

@@ -44,6 +44,7 @@ from typing import Dict, List, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core.policies import EvictionPolicy
 from repro.core.semantic_cache import SemanticCache, SemanticCacheState
@@ -190,14 +191,25 @@ class CooperativeEdgeCluster:
 
         Dead nodes (``node_alive`` False — membership control plane) are
         masked out wholesale: their entries never match a probe, so a
-        crashed shard's data is lost, never phantom-served."""
+        crashed shard's data is lost, never phantom-served.
+
+        With a cache-axis ``mesh`` both stacks are placed over it, shard
+        ``g`` on the mesh's device ``g`` — the layout the collective peer
+        probe expects."""
         if self._keys_stack is None:
-            self._keys_stack = jnp.stack([s.keys for s in self.states])
+            self._keys_stack = self._on_mesh(
+                jnp.stack([s.keys for s in self.states]))
         alive = [self.cache.policy.expire(s, s.clock)
                  if self.node_alive[g] else
                  jnp.zeros((self.cfg.node_capacity,), bool)
                  for g, s in enumerate(self.states)]
-        return self._keys_stack, jnp.stack(alive), alive
+        return self._keys_stack, self._on_mesh(jnp.stack(alive)), alive
+
+    def _on_mesh(self, stack: jax.Array) -> jax.Array:
+        if self.mesh is None:
+            return stack
+        return jax.device_put(stack, NamedSharding(
+            self.mesh, PartitionSpec(self.cache_axis)))
 
     # ------------------------------------------------------------------
     def kill_node(self, node: int) -> None:

@@ -159,10 +159,19 @@ class LocalRung:
         cfg = clusters[0].cfg
         K, N, B, D = queries.shape
         C = cfg.node_capacity
-        l_idx, l_score = similarity_topk_batched(
-            jnp.asarray(queries).reshape(K * N, B, D),
-            ctx.keys.reshape(K * N, C, D),
-            ctx.valid.reshape(K * N, C), 1, impl=cfg.lookup_impl)
+        if K == 1 and getattr(clusters[0], "mesh", None) is not None:
+            # shards live one per device of a cache mesh: each device
+            # probes its own shard, no communication
+            from repro.parallel.sharding import sharded_local_topk
+            l_idx, l_score = sharded_local_topk(
+                jnp.asarray(queries[0]), ctx.keys[0], ctx.valid[0], 1,
+                clusters[0].mesh, clusters[0].cache_axis,
+                impl=cfg.lookup_impl)
+        else:
+            l_idx, l_score = similarity_topk_batched(
+                jnp.asarray(queries).reshape(K * N, B, D),
+                ctx.keys.reshape(K * N, C, D),
+                ctx.valid.reshape(K * N, C), 1, impl=cfg.lookup_impl)
         l_idx = np.asarray(l_idx)[..., 0].reshape(K, N, B)
         l_score = np.asarray(l_score)[..., 0].reshape(K, N, B)
 

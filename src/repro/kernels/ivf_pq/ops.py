@@ -29,7 +29,8 @@ def ivf_pq_probe(queries: jax.Array, home: jax.Array, centroids: jax.Array,
     id per query (a probe never matches its own cluster's rows); index
     arrays as documented in ref.py.  Returns (idx (Q, k) int32 flat
     ``list * cap + slot`` ids, score (Q, k) f32), scores descending, ties
-    toward the lower flat index — bit-exact vs ``ivf_pq_probe_ref``.
+    toward the lower flat index — the indices of ``ivf_pq_probe_ref``,
+    scores to f32 rounding.
 
     impl: auto | pallas | pallas_interpret | ref
     """

@@ -16,7 +16,7 @@ Stage 1 scores every query against every centroid and keeps the top
 ``centroid + decode(codes)`` and runs the usual masked top-k.  Decoding is a
 one-hot matmul (``onehot(codes_s) @ codebook[s]``): each output row copies
 exactly one codebook entry, so the decode is bitwise identical however the
-batch dimensions are blocked — the property the kernel's bit-exactness test
+batch dimensions are blocked — the property the kernel's exact-index test
 leans on.
 
 Flat candidate index = ``list * cap + slot``; callers map it through the

@@ -36,7 +36,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     """In-place paged GQA attention for decode (C == 1) and chunked prefill.
 
     q: (B, C, H, D) chunk queries at absolute positions ``lengths + c``;
-    k/v_pages: (P, page, K, D) physical page pools (H % K == 0);
+    k/v_pages: (P, K, page, D) head-major physical page pools (H % K == 0);
     block_table: (B, n_pages) int32, entries >= P INVALID (skipped);
     lengths: (B,) int32 per-row fill before this dispatch.
     Returns (B, C, H, D).
@@ -51,7 +51,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     # is part of a larger program anyway, so skip straight through
     if active() is None or isinstance(lengths, jax.core.Tracer):
         return _paged_attention(*args, impl=impl)
-    P, page, K, D = (int(s) for s in k_pages.shape)
+    P, K, page, D = (int(s) for s in k_pages.shape)
     modeled = attention_kv_bytes_per_step(
         np.minimum(np.asarray(lengths) + int(q.shape[1]),
                    page * int(block_table.shape[1])),
@@ -69,7 +69,7 @@ def _paged_attention(q, k_pages, v_pages, block_table, lengths, *, impl):
         return paged_attention_ref(q, k_pages, v_pages, block_table, lengths)
 
     B, C, H, D = q.shape
-    K = k_pages.shape[2]
+    K = k_pages.shape[1]
     G = H // K
     # (B, C, H, D) -> (B, K, C*G, D): row c*G + g of group k is chunk
     # offset c of query head g (the kernel recovers c as row // G)
@@ -88,7 +88,7 @@ def attention_kv_bytes_per_step(kv_len, *, page_size: int, max_len: int,
     decode dispatch over rows with ``kv_len`` (array-like) valid tokens
     each (idle rows: kv_len 0).
 
-    ``impl="gather"`` is the ``_paged_view`` path: the pool gather reads
+    ``impl="gather"`` is the ``paged_gather_view`` path: the pool gather reads
     every mapped page, XLA writes the dense (B, max_len) copy, and the
     attention matmul reads that copy back — mapped + 2 * B * max_len
     token-rows per leaf.  ``impl="paged"`` is the in-place kernel: one

@@ -1,6 +1,7 @@
 """Pure-jnp oracle: the gathered-view paged attention the kernel replaces.
 
-Replicates ``models/transformer.py::_paged_view`` + the model's fp32-softmax
+Replicates the model's paged gather view (``paged_gather_view``, which
+``models/transformer.py`` reads the pool through) + its fp32-softmax
 GQA attention bit for bit: gather ``pool[block_table]`` into a dense per-row
 ``(B, n_pages * page)`` copy, mask by absolute position, softmax in fp32.
 This IS the bytes-hungry path the Pallas kernel deletes — kept as the
@@ -14,10 +15,14 @@ import numpy as np
 
 
 def paged_gather_view(pool: jax.Array, block_table: jax.Array) -> jax.Array:
-    """``_paged_view`` semantics: pool (P, page, ...) gathered through
-    block_table (B, n_pages) into (B, n_pages * page, ...).  INVALID
-    entries (>= P) clamp to the last page — junk masked by position."""
-    view = pool[block_table]                   # (B, n_pages, page, ...)
+    """The model's gathered pool view: a head-major attention pool
+    (P, K, page, D) — or a latent pool (P, page, r) — gathered through
+    block_table (B, n_pages) into (B, n_pages * page, K, D) — or
+    (B, n_pages * page, r).  INVALID entries (>= P) clamp to the last
+    page — junk masked by position."""
+    view = pool[block_table]                   # (B, n_pages, [K,] page, ..)
+    if pool.ndim == 4:
+        view = jnp.swapaxes(view, 2, 3)        # (B, n_pages, page, K, D)
     B, n_pages, page = view.shape[:3]
     return view.reshape((B, n_pages * page) + view.shape[3:])
 
@@ -25,13 +30,13 @@ def paged_gather_view(pool: jax.Array, block_table: jax.Array) -> jax.Array:
 def paged_attention_ref(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                         block_table: jax.Array, lengths: jax.Array
                         ) -> jax.Array:
-    """q: (B, C, H, D); k/v_pages: (P, page, K, D); block_table:
+    """q: (B, C, H, D); k/v_pages: (P, K, page, D); block_table:
     (B, n_pages) int32; lengths: (B,) int32 row fill before the dispatch
     (query row c sits at absolute position lengths + c).  Returns
     (B, C, H, D) — the same math as ``L.gqa_attention`` over the gathered
     dense view with the causal mask ``k_pos <= lengths + c``."""
     B, C, H, D = q.shape
-    K = k_pages.shape[2]
+    K = k_pages.shape[1]
     G = H // K
     scale = 1.0 / np.sqrt(D)
     ck = paged_gather_view(k_pages, block_table)         # (B, S, K, D)

@@ -17,9 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.similarity.kernel import (similarity_lookup_kernel,
-                                             similarity_topk_batched_kernel,
-                                             similarity_topk_kernel,
+from repro.kernels.similarity.kernel import (similarity_topk_batched_kernel,
                                              similarity_topk_touch_kernel)
 from repro.kernels.similarity.ref import (similarity_lookup_ref,
                                           similarity_topk_batched_ref,
@@ -73,20 +71,10 @@ def similarity_lookup(queries: jax.Array, keys: jax.Array, valid: jax.Array,
 def _similarity_lookup(queries, keys, valid, *, impl, block_q, block_c):
     if impl == "ref":
         return similarity_lookup_ref(queries, keys, valid)
-
-    Q, D = queries.shape
-    C = keys.shape[0]
-    bq = min(block_q, max(8, Q))
-    bc = min(block_c, max(8, C))
-    pad_q = (-Q) % bq
-    pad_c = (-C) % bc
-    qp = jnp.pad(queries, ((0, pad_q), (0, 0)))
-    kp = jnp.pad(keys, ((0, pad_c), (0, 0)))
-    vp = jnp.pad(valid.astype(jnp.int8), (0, pad_c))
-    idx, score = similarity_lookup_kernel(
-        qp, kp, vp, block_q=bq, block_c=bc,
-        interpret=(impl == "pallas_interpret"))
-    return idx[:Q], score[:Q]
+    # the nearest neighbour is the top-1 of the shared scan kernel
+    idx, score = _topk_pallas(queries[None], keys[None], valid[None], 1,
+                              impl=impl, block_q=block_q, block_c=block_c)
+    return idx[0, :, 0], score[0, :, 0]
 
 
 def similarity_topk(queries: jax.Array, keys: jax.Array, valid: jax.Array,
@@ -119,18 +107,9 @@ def _similarity_topk(queries, keys, valid, *, k, impl, block_q, block_c):
     if impl == "ref":
         return similarity_topk_ref(queries, keys, valid, k)
 
-    Q, D = queries.shape
-    bq = min(block_q, max(8, Q))
-    bc = max(min(block_c, max(8, C)), k)     # kernel needs k <= block_c
-    pad_q = (-Q) % bq
-    pad_c = (-C) % bc
-    qp = jnp.pad(queries, ((0, pad_q), (0, 0)))
-    kp = jnp.pad(keys, ((0, pad_c), (0, 0)))
-    vp = jnp.pad(valid.astype(jnp.int8), (0, pad_c))
-    idx, score = similarity_topk_kernel(
-        qp, kp, vp, k=k, block_q=bq, block_c=bc,
-        interpret=(impl == "pallas_interpret"))
-    return idx[:Q], score[:Q]
+    idx, score = _topk_pallas(queries[None], keys[None], valid[None], k,
+                              impl=impl, block_q=block_q, block_c=block_c)
+    return idx[0], score[0]
 
 
 def similarity_topk_touch(queries: jax.Array, keys: jax.Array,
@@ -180,11 +159,11 @@ def _similarity_topk_touch(queries, keys, valid, last_used, freq, clock,
     pad_q = (-Q) % 8                         # single q-block: pad Q whole
     pad_c = (-C) % bc
     qp = jnp.pad(queries, ((0, pad_q), (0, 0)))
-    qmask = (jnp.ones((Q,), jnp.int8) if mask is None
-             else mask.astype(jnp.int8))
+    qmask = (jnp.ones((Q,), jnp.int32) if mask is None
+             else mask.astype(jnp.int32))
     qmask = jnp.pad(qmask, (0, pad_q))
     kp = jnp.pad(keys, ((0, pad_c), (0, 0)))
-    vp = jnp.pad(valid.astype(jnp.int8), (0, pad_c))
+    vp = jnp.pad(valid.astype(jnp.int32), (0, pad_c))
     lup = jnp.pad(last_used.astype(jnp.int32), (0, pad_c))
     frp = jnp.pad(freq.astype(jnp.int32), (0, pad_c))
     idx, score, lu, fr = similarity_topk_touch_kernel(
@@ -202,8 +181,9 @@ def similarity_topk_batched(queries: jax.Array, keys: jax.Array,
 
     queries: (N, Q, D) unit-norm descriptors; keys: (N, C, D); valid: (N, C)
     bool.  Returns (idx (N, Q, k) int32, score (N, Q, k) f32), scores
-    descending, ties toward the lower cache index — bit-exact vs a vmapped
-    ``similarity_topk_ref``.  k must be <= C.
+    descending, ties toward the lower cache index — the indices of a
+    vmapped ``similarity_topk_ref``, scores to f32 rounding (the kernel
+    tiles the dot products differently).  k must be <= C.
 
     impl: auto | pallas | pallas_interpret | ref
     """
@@ -222,19 +202,27 @@ def similarity_topk_batched(queries: jax.Array, keys: jax.Array,
                    static_argnames=("k", "impl", "block_q", "block_c"))
 def _similarity_topk_batched(queries, keys, valid, *, k, impl, block_q,
                              block_c):
-    N, Q, D = queries.shape
     C = keys.shape[1]
     assert k <= C, (k, C)
     if impl == "ref":
         return similarity_topk_batched_ref(queries, keys, valid, k)
+    return _topk_pallas(queries, keys, valid, k, impl=impl, block_q=block_q,
+                        block_c=block_c)
 
+
+def _topk_pallas(queries, keys, valid, k, *, impl, block_q, block_c):
+    """Pad (N, Q, D) queries and (N, C, D) keys to block multiples and run
+    the shared scan kernel; padded slots are invalid, padded query rows
+    are sliced off."""
+    Q = queries.shape[1]
+    C = keys.shape[1]
     bq = min(block_q, max(8, Q))
     bc = max(min(block_c, max(8, C)), k)     # kernel needs k <= block_c
     pad_q = (-Q) % bq
     pad_c = (-C) % bc
     qp = jnp.pad(queries, ((0, 0), (0, pad_q), (0, 0)))
     kp = jnp.pad(keys, ((0, 0), (0, pad_c), (0, 0)))
-    vp = jnp.pad(valid.astype(jnp.int8), ((0, 0), (0, pad_c)))
+    vp = jnp.pad(valid.astype(jnp.int32), ((0, 0), (0, pad_c)))
     idx, score = similarity_topk_batched_kernel(
         qp, kp, vp, k=k, block_q=bq, block_c=bc,
         interpret=(impl == "pallas_interpret"))

@@ -10,6 +10,11 @@ whose ``cache`` axis spans the cluster's shard holders, bound to
 ``parallel/sharding.py::sharded_topk_lookup`` so a multi-host launch gets
 the peer rung as a shard_map collective (per-device local top-k + one
 (k idx, k score) all-gather) instead of pooling shards on one host.
+
+Every mesh here has ``Auto`` axes: placement is GSPMD's, steered by the
+sharding rules and ``with_sharding_constraint``.  (``jax.make_mesh``
+otherwise defaults to ``Explicit`` axes, whose sharding-in-types rejects
+a gather like the embedding lookup under ZeRO-sharded tables.)
 """
 from __future__ import annotations
 
@@ -17,17 +22,19 @@ import dataclasses
 from typing import Optional
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh (tests / elastic reconfiguration)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """Arbitrary ``Auto``-axis mesh (tests / elastic reconfiguration)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_cache_mesh(num_shards: Optional[int] = None,
@@ -36,7 +43,7 @@ def make_cache_mesh(num_shards: Optional[int] = None,
     every addressable device (a multi-host launch sees the global device
     set, so the axis spans hosts)."""
     n = len(jax.devices()) if num_shards is None else int(num_shards)
-    return jax.make_mesh((n,), (axis_name,))
+    return make_mesh((n,), (axis_name,))
 
 
 @dataclasses.dataclass

@@ -13,6 +13,7 @@ import numpy as np
 from repro.configs import get_config, reduced_config
 from repro.core.coic import CoICConfig
 from repro.core.policies import EvictionPolicy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serving.engine import ServingConfig, ServingEngine
 
@@ -35,6 +36,7 @@ def main():
                          "sequential: one per request (baseline)")
     ap.add_argument("--no-coic", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

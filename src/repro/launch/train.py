@@ -22,6 +22,7 @@ from repro.parallel.sharding import RULES_TRAIN, set_activation_sharder
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.train.trainer import (TrainState, TrainerConfig, init_train_state,
                                  make_train_step)
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -39,6 +40,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

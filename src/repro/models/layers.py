@@ -480,7 +480,6 @@ def moe_apply_dropless_ep(cfg: ModelConfig, p: dict, prefix: str, x: jax.Array,
     more drops under cross-shard load imbalance; covered by the capacity
     factor).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.parallel.sharding import current_sharder
@@ -565,11 +564,11 @@ def moe_apply_dropless_ep(cfg: ModelConfig, p: dict, prefix: str, x: jax.Array,
         w_spec = P(None, None, None)
     wd_spec = P(w_spec[0], w_spec[2], None) if (ep or fp) else P(None, None, None)
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(x_spec, P(None, None), w_spec, w_spec, wd_spec),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p[f"{prefix}/router"], p[f"{prefix}/we_gate"],
       p[f"{prefix}/we_up"], p[f"{prefix}/we_down"])
     if m.num_shared_experts:

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.kernels.paged_attention import paged_attention
+from repro.kernels.paged_attention import paged_attention, paged_gather_view
 from repro.models import layers as L
 from repro.models import ssm as S
 from repro.parallel.sharding import constrain
@@ -428,9 +428,12 @@ class DecoderLM:
     def paged_cache_specs(self, num_pages: int, page_size: int
                           ) -> Dict[str, jax.ShapeDtypeStruct]:
         """ShapeDtypeStructs for the PAGED decode cache: every seq-indexed
-        leaf becomes a physical page pool ``(layers, num_pages, page_size,
-        ...)`` shared by all batch rows through a per-row block table (see
-        ``serving/kv_cache.py::PagedKVCache``).  Page ``num_pages`` is the
+        leaf becomes a physical page pool shared by all batch rows through
+        a per-row block table (see ``serving/kv_cache.py::PagedKVCache``):
+        ``(layers, num_pages, kv_heads, page_size, head_dim)`` for k/v —
+        head-major, so one head's page is the contiguous (page, head_dim)
+        tile the paged-attention kernel DMAs — and ``(layers, num_pages,
+        page_size, r)`` for latent caches.  Page ``num_pages`` is the
         out-of-bounds sink: scatters to it drop, gathers clamp — so an
         INVALID block-table entry can never corrupt a live page.
 
@@ -447,7 +450,7 @@ class DecoderLM:
             for pos, sl in enumerate(seg.pattern):
                 base = f"{seg.name}/{pos}"
                 if sl.kind == "attn":
-                    shp = (R, num_pages, page_size, cfg.num_kv_heads,
+                    shp = (R, num_pages, cfg.num_kv_heads, page_size,
                            cfg.head_dim)
                     specs[f"{base}/k"] = jax.ShapeDtypeStruct(shp, self.dtype)
                     specs[f"{base}/v"] = jax.ShapeDtypeStruct(shp, self.dtype)
@@ -595,14 +598,13 @@ class DecoderLM:
     # (core/layer_reuse.py — the paper's §4 "result of a specific DNN layer").
     # ------------------------------------------------------------------
     @staticmethod
-    def _paged_view(pool: jax.Array, block_table: jax.Array) -> jax.Array:
-        """Gather a dense per-row view ``(B, n_pages*page, ...)`` of a page
-        pool ``(P, page, ...)`` through ``block_table`` (B, n_pages) int32.
-        INVALID entries (== P, out of bounds) clamp to junk that every
-        caller masks by position."""
-        view = pool[block_table]                   # (B, n_pages, page, ...)
-        B, n_pages, page = view.shape[:3]
-        return view.reshape((B, n_pages * page) + view.shape[3:])
+    def _page_write(leaf: jax.Array, pp: jax.Array, off: jax.Array,
+                    vals: jax.Array) -> jax.Array:
+        """Scatter per-token ``vals`` (..., [K,] D) into physical page
+        ``pp`` at offset ``off``; out-of-bounds pages drop the write."""
+        if leaf.ndim == 4:                  # head-major (P, K, page, D)
+            return leaf.at[pp, :, off].set(vals, mode="drop")
+        return leaf.at[pp, off].set(vals, mode="drop")
 
     @staticmethod
     def _page_targets(block_table: jax.Array, positions: jax.Array,
@@ -647,17 +649,16 @@ class DecoderLM:
 
         def write(leaf, vals):
             if block_table is not None:
-                page = leaf.shape[1]
                 pp, off = self._page_targets(block_table, positions, valid,
-                                             page)
-                return leaf.at[pp, off].set(vals, mode="drop")
+                                             leaf.shape[-2])
+                return self._page_write(leaf, pp, off, vals)
             S = leaf.shape[1]
             wpos = positions if valid is None else \
                 jnp.where(valid, positions, S)     # OOB rows drop
             return leaf.at[rows, wpos].set(vals, mode="drop")
 
         def view(leaf):
-            return (self._paged_view(leaf, block_table)
+            return (paged_gather_view(leaf, block_table)
                     if block_table is not None else leaf)
 
         if sl.kind == "attn":
@@ -748,7 +749,7 @@ class DecoderLM:
 
         ``attn_impl`` selects how paged attention reads the pool:
         ``"gather"`` (default) materializes the per-row dense view
-        (``_paged_view``, the bit-exactness oracle); any
+        (``paged_gather_view``, the bit-exactness oracle); any
         ``kernels/paged_attention`` impl (``"auto"`` / ``"pallas"`` /
         ``"pallas_interpret"`` / ``"ref"``) reads pages in place through
         the fused Pallas kernel.  Ignored without a block table.
@@ -827,16 +828,15 @@ class DecoderLM:
 
         def write(leaf, vals):                     # vals: (B, ...) one token
             if block_table is not None:
-                page = leaf.shape[1]
                 pp, off = self._page_targets(block_table, positions,
-                                             None, page)
-                return leaf.at[pp[:, 0], off[:, 0]].set(vals, mode="drop")
+                                             None, leaf.shape[-2])
+                return self._page_write(leaf, pp[:, 0], off[:, 0], vals)
             Sk = leaf.shape[1]
             return leaf.at[jnp.arange(Bsz), lengths % Sk].set(vals,
                                                               mode="drop")
 
         def view(leaf):
-            return (self._paged_view(leaf, block_table)
+            return (paged_gather_view(leaf, block_table)
                     if block_table is not None else leaf)
 
         if sl.kind == "attn":

@@ -57,7 +57,9 @@ tier gets, which is what keeps the engine's per-step ladder bound constant:
 * ``sharded_topk_lookup`` — the same peer-rung collective as a
   ``shard_map`` over a real ``cache`` mesh axis: each device computes its
   local top-k and one all-gather of (k idx, k score) per shard replaces
-  shipping whole shards around.
+  shipping whole shards around.  ``sharded_local_topk`` is the local
+  rung on that mesh: each device probes only the shard it holds with its
+  own node's queries, no communication.
 """
 from __future__ import annotations
 
@@ -435,7 +437,6 @@ def sharded_topk_lookup(queries: jax.Array, keys: jax.Array,
     Returns replicated (idx (Q, k), score (Q, k)), identical to the
     single-device ``cluster_topk_lookup`` result.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.kernels.similarity import similarity_topk
@@ -452,11 +453,33 @@ def sharded_topk_lookup(queries: jax.Array, keys: jax.Array,
         g_score = jax.lax.all_gather(score, axis_name)
         return _merge_shard_topk(g_idx, g_score, min(k, n * c))
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(axis_name), P(axis_name)),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
+    )(queries, keys, valid)
+
+
+def sharded_local_topk(queries: jax.Array, keys: jax.Array,
+                       valid: jax.Array, k: int, mesh: Mesh,
+                       axis_name: str = "cache", *, impl: str = "auto"):
+    """The local rung over a ``cache`` mesh: queries (N, B, D), keys
+    (N, C, D) and valid (N, C) sharded over ``axis_name`` on dim 0, node
+    ``n``'s queries scored against shard ``n`` on the device that holds
+    it.  Returns (idx (N, B, k), score (N, B, k)) sharded likewise, equal
+    to ``similarity_topk_batched`` over the stacks (a Pallas kernel
+    cannot be partitioned by the compiler, so each device runs it on its
+    own block)."""
+    from repro.kernels.similarity import similarity_topk_batched
+
+    assert keys.shape[0] == mesh.shape[axis_name], (
+        keys.shape, dict(mesh.shape))
+    spec = P(axis_name)
+    return jax.shard_map(
+        lambda q, kk, vv: similarity_topk_batched(q, kk, vv, k, impl=impl),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=(spec, spec),
+        check_vma=False,
     )(queries, keys, valid)
 
 

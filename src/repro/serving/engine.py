@@ -158,8 +158,8 @@ class ServingConfig:
     kv_page: int = 0
     kv_pages: int = 0                # pool size (0 = 2x max_batch span)
     # attention read over the paged pool: "gather" materializes the dense
-    # per-row view (_paged_view — the bytes-hungry oracle), "paged" reads
-    # KV pages in place via the fused kernels/paged_attention op (Pallas
+    # per-row view (paged_gather_view — the bytes-hungry oracle), "paged"
+    # reads KV pages in place via the fused kernels/paged_attention op (Pallas
     # on TPU, jnp oracle elsewhere), "paged_interpret" forces the Pallas
     # interpreter (CI bit-exactness).  Requires kv_page > 0.
     attn_impl: str = "gather"        # gather | paged | paged_interpret

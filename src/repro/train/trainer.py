@@ -120,7 +120,6 @@ def make_train_step_compressed(model, tcfg: TrainerConfig, mesh,
     Returns (train_step(state, err_state, batch) -> (state, err_state,
     metrics)).  Requires a mesh with a ``pod`` axis.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.optim.grad_compress import (CompressionState,
@@ -137,9 +136,6 @@ def make_train_step_compressed(model, tcfg: TrainerConfig, mesh,
 
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
-    # non-pod mesh axes stay in GSPMD's hands inside the shard_map (intra-pod
-    # FSDP/TP unchanged); only the pod axis is manual.
-    auto_axes = frozenset(a for a in mesh.shape if a != "pod")
 
     def train_step(state: TrainState, err: dict, batch: dict):
         def pod_local(params, pod_batch, pod_err):
@@ -152,17 +148,18 @@ def make_train_step_compressed(model, tcfg: TrainerConfig, mesh,
             return grads, new_err, loss
 
         # params replicated across pods; batch sharded over pod; error local.
-        # Only the pod axis is manual; ``auto`` leaves the other mesh axes to
-        # GSPMD inside the body (intra-pod FSDP/TP unchanged).
+        # Only the pod axis is manual (``axis_names``); the other mesh axes
+        # stay in GSPMD's hands inside the body (intra-pod FSDP/TP
+        # unchanged).
         p_spec = jax.tree.map(lambda _: P(), state.params)
         b_spec = jax.tree.map(lambda _: P("pod"), batch)
         e_spec = jax.tree.map(lambda _: P("pod"), err)
-        grads, new_err, loss = shard_map(
+        grads, new_err, loss = jax.shard_map(
             pod_local, mesh=mesh,
             in_specs=(p_spec, b_spec, e_spec),
             out_specs=(p_spec, e_spec, P()),
-            auto=auto_axes,
-            check_rep=False,
+            axis_names=frozenset({"pod"}),
+            check_vma=False,
         )(state.params, batch, err)
 
         new_params, new_opt, opt_metrics = optimizer.update(

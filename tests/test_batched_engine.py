@@ -17,13 +17,18 @@ from repro.kernels.similarity import (similarity_topk_batched,
                                       similarity_topk_batched_ref)
 
 
+# a few f32 ULPs of a unit-norm dot product
+SCORE_ATOL = 1e-6
+
+
 def _unit(rng, *shape):
     x = rng.standard_normal(shape).astype(np.float32)
     return x / np.linalg.norm(x, axis=-1, keepdims=True)
 
 
 # ---------------------------------------------------------------------------
-# batched kernel vs the vmapped jnp oracle (bit-exact, tie-breaks included)
+# batched kernel vs the vmapped jnp oracle (exact indices, tie-breaks
+# included; scores to SCORE_ATOL)
 # ---------------------------------------------------------------------------
 
 
@@ -42,7 +47,12 @@ class TestBatchedTopK:
                                          jnp.asarray(valid), k,
                                          impl="pallas_interpret",
                                          block_q=32, block_c=64)
-        assert np.array_equal(np.asarray(rs), np.asarray(ps))
+        # scores: within a few f32 ULPs of a unit-norm dot product (the
+        # kernel's tiled matmul accumulates in another order than the
+        # oracle's einsum, in interpret mode and on the MXU alike);
+        # indices: exact
+        np.testing.assert_allclose(np.asarray(ps), np.asarray(rs),
+                                   rtol=0, atol=SCORE_ATOL)
         real = np.asarray(rs) > -1e29
         assert np.array_equal(np.asarray(ri)[real], np.asarray(pi)[real])
 

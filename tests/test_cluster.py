@@ -21,6 +21,13 @@ def _unit(rng, n, d):
     return x / np.linalg.norm(x, axis=1, keepdims=True)
 
 
+def _dyadic(rng, n, d):
+    """Vectors of eighths in [-1/2, 1/2]: every partial dot product is
+    exact in f32, so scores agree bit for bit whatever the summation order
+    of the matmul shapes compared, and ties are frequent."""
+    return (rng.integers(-4, 5, size=(n, d)) / 8).astype(np.float32)
+
+
 # ---------------------------------------------------------------------------
 # sharded/tiled top-k vs the single-device jnp oracle
 # ---------------------------------------------------------------------------
@@ -51,10 +58,12 @@ class TestTopK:
     def test_cluster_lookup_bitexact_vs_pooled_oracle(self, n, c, q, d, k):
         """The vmapped cluster-wide lookup over stacked shards must match a
         single jnp top-k over the pooled key matrix BIT-exactly — scores and
-        indices — including tie-breaks."""
+        indices — including tie-breaks (dyadic inputs: the matmul shapes
+        differ, so only exact partial sums make bit-equality a property of
+        the selection, not of the host's summation order)."""
         rng = np.random.default_rng(n * 1000 + c)
-        keys = _unit(rng, n * c, d).reshape(n, c, d)
-        qs = _unit(rng, q, d)
+        keys = _dyadic(rng, n * c, d).reshape(n, c, d)
+        qs = _dyadic(rng, q, d)
         valid = rng.random((n, c)) > 0.3
         gi, gs = cluster_topk_lookup(jnp.asarray(qs), jnp.asarray(keys),
                                      jnp.asarray(valid), k)
@@ -79,7 +88,8 @@ class TestTopK:
     @pytest.mark.slow
     def test_shard_map_lookup_bitexact(self):
         """shard_map over a real 4-device ``cache`` mesh == pooled oracle,
-        bit-exact (subprocess: XLA locks host device count at first init)."""
+        bit-exact on dyadic inputs (see ``_dyadic``; subprocess: XLA locks
+        host device count at first init)."""
         import os
         import subprocess
         import sys
@@ -93,10 +103,8 @@ class TestTopK:
             mesh = jax.make_mesh((4,), ("cache",))
             rng = np.random.default_rng(2)
             n, c, q, d, k = 4, 32, 6, 16, 5
-            keys = rng.standard_normal((n, c, d)).astype(np.float32)
-            keys /= np.linalg.norm(keys, axis=-1, keepdims=True)
-            qs = rng.standard_normal((q, d)).astype(np.float32)
-            qs /= np.linalg.norm(qs, axis=-1, keepdims=True)
+            keys = (rng.integers(-4, 5, size=(n, c, d)) / 8).astype(np.float32)
+            qs = (rng.integers(-4, 5, size=(q, d)) / 8).astype(np.float32)
             valid = rng.random((n, c)) > 0.3
             si, ss = sharded_topk_lookup(jnp.asarray(qs), jnp.asarray(keys),
                                          jnp.asarray(valid), k, mesh)
@@ -114,6 +122,55 @@ class TestTopK:
         r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                            text=True, env=env, timeout=300)
         assert "SHARDED_TOPK_OK" in r.stdout, r.stdout + r.stderr
+
+    @pytest.mark.slow
+    def test_mesh_cluster_serves_like_pooled_cluster(self):
+        """A cluster on a 4-device ``cache`` mesh keeps shard g on device g
+        (local rung per device, peer rung a collective) and serves what
+        the same cluster pooled on one device serves (subprocess: XLA
+        locks host device count at first init)."""
+        import os
+        import subprocess
+        import sys
+        import textwrap
+
+        src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
+        code = textwrap.dedent("""
+            import numpy as np, jax.numpy as jnp
+            from repro.core.cluster import ClusterConfig, CooperativeEdgeCluster
+            from repro.core.tiers import TIER_PEER
+            from repro.launch.mesh import make_cache_mesh
+            rng = np.random.default_rng(4)
+            n, d, p = 4, 32, 4
+            pool = rng.standard_normal((24, d)).astype(np.float32)
+            pool /= np.linalg.norm(pool, axis=-1, keepdims=True)
+            pay = rng.standard_normal((24, p)).astype(np.float32)
+            cfg = ClusterConfig(num_nodes=n, node_capacity=16, key_dim=d,
+                                payload_dim=p, threshold=0.9)
+            cls = [CooperativeEdgeCluster(cfg, mesh=make_cache_mesh(n)),
+                   CooperativeEdgeCluster(cfg)]
+            for g in range(n):
+                ids = rng.integers(0, 24, size=5)
+                for cl in cls:
+                    cl.insert(g, jnp.asarray(pool[ids]), jnp.asarray(pay[ids]))
+            keys, _, _ = cls[0]._stacks()
+            assert sorted(s.device.id for s in keys.addressable_shards) == \\
+                [0, 1, 2, 3]
+            q = jnp.asarray(pool[rng.integers(0, 24, size=(n, 6))])
+            got, want = (cl.lookup_grouped(q) for cl in cls)
+            for name in ("hit", "tier", "owner", "value"):
+                np.testing.assert_array_equal(getattr(got, name),
+                                              getattr(want, name))
+            np.testing.assert_allclose(got.score, want.score, atol=1e-6)
+            assert (np.asarray(got.tier) == TIER_PEER).any()
+            print("MESH_CLUSTER_OK")
+        """)
+        env = dict(os.environ)
+        env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+        r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                           text=True, env=env, timeout=300)
+        assert "MESH_CLUSTER_OK" in r.stdout, r.stdout + r.stderr
 
 
 # ---------------------------------------------------------------------------

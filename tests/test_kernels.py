@@ -22,6 +22,11 @@ from repro.kernels.similarity import (similarity_lookup, similarity_topk_touch,
 from repro.serving.kv_cache import PagedKVCache
 
 
+# f32 agreement of a kernel's similarity scores with its jnp oracle: a few
+# ULPs of an O(1) dot product, whatever the accumulation order
+IVF_SCORE_TOL = dict(rtol=1e-6, atol=1e-6)
+
+
 def _unit(rng, *shape):
     x = rng.normal(size=shape).astype(np.float32)
     return x / np.linalg.norm(x, axis=-1, keepdims=True)
@@ -125,8 +130,8 @@ def _paged_case(rng, *, B, page, n_pages, K, D, H=None, C=1, lengths=None,
     if lengths is None:
         lengths = rng.integers(0, n_pages * page - C + 1, size=(B,))
     lengths = np.asarray(lengths, np.int32)
-    kp = rng.normal(size=(P, page, K, D)).astype(dtype)
-    vp = rng.normal(size=(P, page, K, D)).astype(dtype)
+    kp = rng.normal(size=(P, K, page, D)).astype(dtype)
+    vp = rng.normal(size=(P, K, page, D)).astype(dtype)
     bt = np.full((B, n_pages), PagedKVCache.INVALID, np.int32)
     nxt = shared_pages
     for b in range(B):
@@ -144,8 +149,9 @@ def _paged_case(rng, *, B, page, n_pages, K, D, H=None, C=1, lengths=None,
 
 class TestPagedAttention:
     """Fused in-place paged attention vs the gather-path oracle (ref.py
-    replicates ``_paged_view`` + the model's fp32-softmax GQA bit for bit,
-    so ref-vs-interpret closeness here transfers to the serving path)."""
+    replicates ``paged_gather_view`` + the model's fp32-softmax GQA bit for
+    bit, so ref-vs-interpret closeness here transfers to the serving
+    path)."""
 
     @pytest.mark.parametrize("B,page,n_pages,K,H,D,C", [
         (3, 16, 4, 2, 4, 16, 1),       # GQA decode
@@ -377,10 +383,27 @@ class TestKernelVsModelAttention:
 
 class TestIVFPQ:
     """Two-stage IVF-PQ digest probe: the Pallas body (interpret=True) must
-    be BIT-exact against the jnp oracle — idx, score AND the probed-list
-    selection.  The decode is a one-hot matmul (copies codebook entries
-    exactly) and the merge replays ``lax.top_k`` tie order, so equality is
-    ``assert_array_equal``, not allclose."""
+    pick EXACTLY the oracle's probed lists and candidate indices
+    (``assert_array_equal``): the decode is a one-hot matmul (copies
+    codebook entries exactly) and the merge replays ``lax.top_k`` tie
+    order.  Scores agree to ``IVF_SCORE_TOL``, a few f32 ULPs: the kernel's
+    per-list matmul accumulates the D-long dot product in another order
+    than the oracle's single (Q, L*cap) matmul, in interpret mode and on
+    the MXU alike.  A row with fewer than k candidates pads with NEG_INF
+    scores whose indices are unspecified (the kernel never visits lists
+    no query of the tile probed), so index equality covers the real
+    candidates, as in the similarity kernels' tests."""
+
+    @staticmethod
+    def _assert_matches(ref, pal):
+        (i_ref, s_ref, sel_ref), (i_pal, s_pal, sel_pal) = (
+            [np.asarray(a) for a in r] for r in (ref, pal))
+        np.testing.assert_array_equal(sel_ref, sel_pal, err_msg="sel")
+        np.testing.assert_allclose(s_pal, s_ref, err_msg="score",
+                                   **IVF_SCORE_TOL)
+        real = s_ref > -1e29
+        np.testing.assert_array_equal(i_ref[real], i_pal[real],
+                                      err_msg="idx")
 
     @staticmethod
     def _index(rng, L, cap, S, D, K):
@@ -401,7 +424,8 @@ class TestIVFPQ:
 
     @pytest.mark.parametrize("Q,L,cap,S,D,n_probe,k",
                              [(8, 8, 4, 2, 16, 3, 1), (16, 16, 8, 4, 32, 4, 4),
-                              (8, 12, 6, 4, 16, 12, 2), (24, 9, 5, 8, 64, 1, 3)])
+                              (8, 12, 6, 4, 16, 12, 2), (24, 9, 5, 8, 64, 1, 3),
+                              (8, 4, 1100, 4, 16, 2, 4)])   # lists in tiles
     def test_kernel_bit_exact_vs_oracle(self, Q, L, cap, S, D, n_probe, k,
                                         nprng):
         from repro.kernels.ivf_pq.kernel import ivf_pq_probe_kernel
@@ -411,18 +435,14 @@ class TestIVFPQ:
         idxarrs = self._index(nprng, L, cap, S, D, K)
         q = jnp.asarray(_unit(nprng, Q, D))
         home = jnp.asarray(nprng.integers(0, K, size=Q).astype(np.int32))
-        i_ref, s_ref, sel_ref = ivf_pq_probe_ref(q, home, *idxarrs, k=k,
-                                                 n_probe=n_probe)
-        i_pal, s_pal, sel_pal = ivf_pq_probe_kernel(q, home, *idxarrs, k=k,
-                                                    n_probe=n_probe,
-                                                    interpret=True)
-        np.testing.assert_array_equal(np.asarray(sel_ref), np.asarray(sel_pal))
-        np.testing.assert_array_equal(np.asarray(i_ref), np.asarray(i_pal))
-        np.testing.assert_array_equal(np.asarray(s_ref), np.asarray(s_pal))
+        self._assert_matches(
+            ivf_pq_probe_ref(q, home, *idxarrs, k=k, n_probe=n_probe),
+            ivf_pq_probe_kernel(q, home, *idxarrs, k=k, n_probe=n_probe,
+                                interpret=True))
 
     def test_ops_pads_ragged_query_tile(self, nprng):
         """Public wrapper pads Q to a multiple of 8 (padded rows home=-1)
-        and slices the outputs back — still bit-exact vs the ref impl."""
+        and slices the outputs back — same indices as the ref impl."""
         from repro.kernels.ivf_pq import ivf_pq_probe
 
         idxarrs = self._index(nprng, 8, 4, 2, 16, 2)
@@ -432,8 +452,8 @@ class TestIVFPQ:
                                     impl="ref")
         i_pal, s_pal = ivf_pq_probe(q, home, *idxarrs, k=2, n_probe=3,
                                     impl="pallas_interpret")
-        np.testing.assert_array_equal(np.asarray(i_ref), np.asarray(i_pal))
-        np.testing.assert_array_equal(np.asarray(s_ref), np.asarray(s_pal))
+        sel = np.zeros((5, 3), np.int32)              # not returned here
+        self._assert_matches((i_ref, s_ref, sel), (i_pal, s_pal, sel))
 
     def test_auto_routes_to_ref_off_tpu(self, nprng):
         """CI has no TPU: auto must be the jnp oracle, bit for bit."""
@@ -518,12 +538,10 @@ class TestIVFPQ:
             idxarrs = self._index(rng, L, cap, S, 16, 3)
             q = jnp.asarray(_unit(rng, Q, 16))
             home = jnp.asarray(rng.integers(0, 3, size=Q).astype(np.int32))
-            ref = ivf_pq_probe_ref(q, home, *idxarrs, k=k, n_probe=n_probe)
-            pal = ivf_pq_probe_kernel(q, home, *idxarrs, k=k,
-                                      n_probe=n_probe, interpret=True)
-            for a, b, name in zip(ref, pal, ("idx", "score", "sel")):
-                np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
-                                              err_msg=name)
+            self._assert_matches(
+                ivf_pq_probe_ref(q, home, *idxarrs, k=k, n_probe=n_probe),
+                ivf_pq_probe_kernel(q, home, *idxarrs, k=k, n_probe=n_probe,
+                                    interpret=True))
 
         check()
 

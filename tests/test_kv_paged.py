@@ -90,7 +90,10 @@ def test_tail_chunk_one_compile_across_remainders(fp32_model, nprng):
 # ---------------------------------------------------------------------------
 
 
-def _assert_paged_matches_dense(model, params, prompts):
+def _assert_paged_matches_dense(model, params, prompts, expect_share=True):
+    """``expect_share``: some prompt is admitted after an earlier one with
+    the same head has registered its pages (more prompts than the 4 slots;
+    prompts admitted in one step all compute their heads)."""
     eng_d, toks_d = _serve(model, params, prompts)
     eng_p, toks_p = _serve(model, params, prompts, kv_page=16,
                            prefill_chunk=32)
@@ -105,9 +108,13 @@ def _assert_paged_matches_dense(model, params, prompts):
         assert (eng.kv.refcount == 0).all()
         assert (eng.kv.block_table == PagedKVCache.INVALID).all()
         assert eng.stats()["kv"]["pages_in_use"] == 0
-    assert eng_p.prefill_tokens_shared > 0
     assert eng_n.prefill_tokens_shared == 0
-    assert eng_p.prefill_tokens_computed < eng_n.prefill_tokens_computed
+    if expect_share:
+        assert eng_p.prefill_tokens_shared > 0
+        assert eng_p.prefill_tokens_computed < eng_n.prefill_tokens_computed
+    else:
+        assert (eng_p.prefill_tokens_computed
+                == eng_n.prefill_tokens_computed)
     return eng_p
 
 
@@ -138,7 +145,8 @@ def test_paged_prefix_sharing_bit_identical_hypothesis(fp32_model):
         prompts = _shared_prefix_prompts(
             rng, cfg.vocab_size, n_req,
             prefix_lens=tuple(16 + e for e in prefix_extra))
-        _assert_paged_matches_dense(model, params, prompts)
+        _assert_paged_matches_dense(model, params, prompts,
+                                    expect_share=n_req > 4)
 
     prop()
 

@@ -220,7 +220,6 @@ def test_compressed_cross_pod_psum():
     code = """
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.optim.grad_compress import (compressed_cross_pod_mean,
                                            init_compression_state)
 
@@ -234,9 +233,9 @@ def test_compressed_cross_pod_psum():
             {"w": gl["w"][0]}, state._replace(error={"w": err["w"][0]}), "pod")
         return out["w"], new_state.error["w"]
 
-    sm = shard_map(f, mesh=mesh,
-                   in_specs=({"w": P("pod")}, {"w": P("pod")}),
-                   out_specs=(P(), P("pod")))
+    sm = jax.shard_map(f, mesh=mesh,
+                       in_specs=({"w": P("pod")}, {"w": P("pod")}),
+                       out_specs=(P(), P("pod")))
     err0 = {"w": jnp.zeros((2, 64), jnp.float32)}
     mean, new_err = sm(g, err0)
     want = np.asarray(g["w"]).mean(0)
