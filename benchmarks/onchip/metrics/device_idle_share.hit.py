@@ -1,0 +1,10 @@
+"""Device: share of the traced window in which no operation ran on the
+chip, in %."""
+
+UNIT = "%"
+LAYER = "device"
+MOVES = "hit_p95_ms"
+
+
+def read(ctx):
+    return ctx.idle_share()

@@ -1,0 +1,10 @@
+"""Front end (``serving/engine.py`` step loop): the window over the
+engine steps it held, in ms."""
+
+UNIT = "ms"
+LAYER = "front end"
+MOVES = "out_tok_s"
+
+
+def read(ctx):
+    return ctx.step_ms()
