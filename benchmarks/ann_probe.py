@@ -16,11 +16,9 @@ ALSO survives the full-precision confirm (true cosine of the returned
 row >= tau) — the end-to-end serve-rate ratio, not a raw top-k overlap,
 because the confirm is what gates a remote serve either way.
 
-Scanned bytes/row come from the ``obs/profile.py`` wire models — the
-measured paths run under ``enable_profiling`` and the reported numbers
-are read back from the ``kernel/<op>/<impl>/modeled_bytes`` counters, so
-the benchmark exercises the same hooks the engines use.  The 1M and 10M
-rows-per-region points are modeled with the same byte formulas (the
+Scanned bytes/row come from the ``obs/profile.py`` wire models; each
+probe's latency is its wall time to ``block_until_ready``.  The 1M and
+10M rows-per-region points are modeled with the same byte formulas (the
 index layout is scale-free); latency is measured at the build scale.
 
 The ``ann_accept`` row is what the nightly smoke pins:
@@ -117,8 +115,7 @@ def run(seed: int = 0, rows: int = 100_000, K: int = 4, B: int = 64,
         train_rows: int = 8192, smoke: bool = False, json_path: str = ""):
     from repro.core.digest import (build_ivfpq_index, quantize_rows,
                                    train_pq_codebook)
-    from repro.obs.metrics import MetricsRegistry
-    from repro.obs.profile import (disable_profiling, enable_profiling)
+    from repro.kernels.similarity.ops import resolve_impl
     from repro.parallel.sharding import (federated_digest_lookup,
                                          federated_digest_lookup_ivfpq,
                                          federated_digest_lookup_quantized)
@@ -149,22 +146,15 @@ def run(seed: int = 0, rows: int = 100_000, K: int = 4, B: int = 64,
                            seed=seed, iters=4)
     index = build_ivfpq_index(cb, keys, valid, owner)
 
-    metrics = MetricsRegistry()
-    enable_profiling(metrics)
-    try:
-        us32, (i32, s32) = _time_us(
-            lambda: federated_digest_lookup(queries, digests, dvalid, 1))
-        us8, (i8, s8) = _time_us(
-            lambda: federated_digest_lookup_quantized(
-                queries, codes8, scales8, dvalid, 1))
-        usq, (iq, sq) = _time_us(
-            lambda: federated_digest_lookup_ivfpq(queries, index, 1,
-                                                  n_probe=n_probe))
-    finally:
-        disable_profiling()
-    impl = next(n for n in metrics.names()
-                if n.startswith("kernel/federated_digest_lookup/")
-                ).split("/")[2]
+    us32, (i32, s32) = _time_us(
+        lambda: federated_digest_lookup(queries, digests, dvalid, 1))
+    us8, (i8, s8) = _time_us(
+        lambda: federated_digest_lookup_quantized(
+            queries, codes8, scales8, dvalid, 1))
+    usq, (iq, sq) = _time_us(
+        lambda: federated_digest_lookup_ivfpq(queries, index, 1,
+                                              n_probe=n_probe))
+    impl = resolve_impl("auto")
 
     # recall@confirm: would the candidate survive the full-precision
     # confirm (true cosine >= TAU)?  fp32's candidates are the baseline.

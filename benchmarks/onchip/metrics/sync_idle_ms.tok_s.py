@@ -1,0 +1,12 @@
+"""Front end (``serving/engine.py`` step, ``core/tiers.py`` rungs): device
+idle time per engine step whose innermost program span is a host read
+(``d2h:*``) or upload (``h2d:*``), in ms."""
+from harness import program_spans
+
+UNIT = "ms"
+LAYER = "front end"
+MOVES = "out_tok_s"
+
+
+def read(ctx):
+    return program_spans.sync_idle_ms(ctx)

@@ -26,6 +26,7 @@ of a passing run is one JSON object naming the device.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import re
 import sys
@@ -46,8 +47,6 @@ from repro.kernels.paged_attention import paged_attention  # noqa: E402
 from repro.kernels.similarity import (similarity_topk,  # noqa: E402
                                       similarity_topk_batched)
 from repro.models import build_model  # noqa: E402
-from repro.obs.metrics import MetricsRegistry  # noqa: E402
-from repro.obs.profile import disable_profiling, enable_profiling  # noqa: E402
 from repro.serving.engine import ServingConfig, ServingEngine  # noqa: E402
 from repro.serving.kv_cache import PagedKVCache  # noqa: E402
 
@@ -81,15 +80,15 @@ def describe(cfg) -> str:
             f"vocab {cfg.vocab_size}, {cfg.dtype}")
 
 
-def _attention_impl(lowered) -> str:
-    """What a lowered engine step runs for paged attention: ``pallas`` where
-    the Mosaic kernel is in the program, ``pallas_interpret`` where the
-    kernel's jitted wrapper is lowered for the interpreter (off the chip),
-    else ``ref``."""
+def _kernel_impl(lowered, kernel: str, wrapper: str) -> str:
+    """What a lowered program runs for the Pallas kernel named ``kernel``:
+    ``pallas`` where the Mosaic kernel is in the program,
+    ``pallas_interpret`` where its jitted ``wrapper`` is lowered for the
+    interpreter (off the chip), else ``ref``."""
     text = lowered.as_text()
-    if "paged_attention" in re.findall(r'kernel_name = "([^"]+)"', text):
+    if kernel in re.findall(r'kernel_name = "([^"]+)"', text):
         return "pallas"
-    if "@paged_attention_kernel" in text:
+    if f"@{wrapper}" in text:
         return "pallas_interpret"
     return "ref"
 
@@ -125,21 +124,16 @@ def serve_smoke(cfg, *, seed: int = 0, impl: str = "pallas",
                         threshold=HIT_THRESHOLD,
                         lookup_impl="auto" if on_chip else impl)))
 
-    prof = MetricsRegistry()
-    enable_profiling(prof)
-    try:
-        prompt_of = {}
-        t0 = time.perf_counter()
-        half = n_requests // 2
-        for wave in (order[:half], order[half:]):
-            for p in wave:
-                rid = eng.submit(prompts[p], node_id=len(prompt_of) % num_nodes)
-                prompt_of[rid] = int(p)
-            eng.run_until_drained()
-        jax.block_until_ready(eng.cache)
-        served_s = time.perf_counter() - t0
-    finally:
-        disable_profiling()
+    prompt_of = {}
+    t0 = time.perf_counter()
+    half = n_requests // 2
+    for wave in (order[:half], order[half:]):
+        for p in wave:
+            rid = eng.submit(prompts[p], node_id=len(prompt_of) % num_nodes)
+            prompt_of[rid] = int(p)
+        eng.run_until_drained()
+    jax.block_until_ready(eng.cache)
+    served_s = time.perf_counter() - t0
 
     results = eng.results
     stats = eng.stats()
@@ -166,14 +160,18 @@ def serve_smoke(cfg, *, seed: int = 0, impl: str = "pallas",
                f"hit {r.req_id} ({r.source}) returned tokens that no miss "
                "of its prompt decoded")
 
-    # resolved impl of every kernel op: host-side ops from the profiler's
-    # kernel/<op>/<impl>/ names, the attention kernel from the lowered
-    # programs of the engine's jitted paged decode and chunk steps
-    impls = {}
-    for name in prof.names():
-        parts = name.split("/")
-        if parts[0] == "kernel" and parts[-1] == "calls":
-            impls.setdefault(parts[1], set()).add(parts[2])
+    # resolved impl of every kernel op, from lowered programs: the
+    # ladder's probe as the cluster calls it, and the engine's jitted
+    # paged decode and chunk steps
+    _check(stats["ladder"]["rung_dispatches"]["local"] > 0,
+           "the ladder never ran similarity_topk_batched")
+    keys, valid, _ = eng.sem_cluster._stacks()
+    probe = jax.jit(functools.partial(
+        similarity_topk_batched, k=1, impl=eng.sem_cluster.cfg.lookup_impl))
+    q = jnp.zeros((keys.shape[0], 1, keys.shape[-1]), jnp.float32)
+    impls = {"similarity_topk_batched": {_kernel_impl(
+        probe.lower(q, keys, valid), "similarity_topk",
+        "similarity_topk_batched_kernel")}}
     bt = jnp.asarray(eng.kv.decode_table(eng.row_active))
     C = eng._chunk_width
     lowered = {
@@ -184,12 +182,11 @@ def serve_smoke(cfg, *, seed: int = 0, impl: str = "pallas",
             jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32), bt[:1]),
     }
     for step, low in lowered.items():
-        impls[f"paged_attention@{step}"] = {_attention_impl(low)}
+        impls[f"paged_attention@{step}"] = {_kernel_impl(
+            low, "paged_attention", "paged_attention_kernel")}
     report["impls"] = {k: sorted(v) for k, v in impls.items()}
     for op, got in sorted(report["impls"].items()):
         log(f"kernel {op}: {','.join(got)}")
-    _check("similarity_topk_batched" in impls,
-           "the ladder never ran similarity_topk_batched")
     for op, got in impls.items():
         _check(got == {impl}, f"{op} resolved to {sorted(got)}, not {impl}")
 

@@ -52,6 +52,7 @@ from repro.core.tiers import (TIER_LOCAL, TIER_MISS, TIER_NAMES, TIER_PEER,
                               LocalRung, PeerRung, TierLadder,
                               TierProbeResult, build_probe_context, pow2,
                               route_flat)
+from repro.obs.trace import NULL_TRACER, to_host
 
 # canonical codes/names re-exported from core/tiers.py: cluster results use
 # the same TIER_LOCAL=0 / TIER_PEER=1 / TIER_MISS=3 codes as every layer
@@ -63,7 +64,7 @@ __all__ = ["TIER_LOCAL", "TIER_PEER", "TIER_MISS", "TIER_NAMES",
 
 def admission_filter(kind: str, slots: np.ndarray, owner_state,
                      node_state, policy, seen: Dict[tuple, int],
-                     key_prefix: tuple) -> np.ndarray:
+                     key_prefix: tuple, tracer=NULL_TRACER) -> np.ndarray:
     """Which remotely-served cache ``slots`` (entries of ``owner_state`` just
     served to another node or cluster) get re-admitted into the requester's
     shard (``node_state``).  Shared by the peer tier and the federation
@@ -79,6 +80,9 @@ def admission_filter(kind: str, slots: np.ndarray, owner_state,
                       requester shard's coldest victim's count (free slots
                       count 0), so replication never displaces an entry
                       hotter than the newcomer
+
+    The host reads of shard state it makes go through ``to_host`` and
+    report to ``tracer``.
     """
     n = len(slots)
     if kind == "never":
@@ -86,7 +90,7 @@ def admission_filter(kind: str, slots: np.ndarray, owner_state,
     if kind == "always":
         return np.ones((n,), bool)
     if kind == "second_hit":
-        ins = np.asarray(owner_state.inserted_at)
+        ins = to_host(tracer, "inserted_at", owner_state.inserted_at)
         admit = np.zeros((n,), bool)
         for i, slot in enumerate(np.asarray(slots)):
             key = key_prefix + (int(slot), int(ins[slot]))
@@ -95,11 +99,12 @@ def admission_filter(kind: str, slots: np.ndarray, owner_state,
         return admit
     assert kind == "freq_weighted", kind
     # argmin ties to the lower slot, matching insert()'s top_k(-pri) victim
-    pri = np.asarray(policy.priority(node_state))
+    pri = to_host(tracer, "priority", policy.priority(node_state))
     victim = int(np.argmin(pri))
-    vfreq = (int(np.asarray(node_state.freq)[victim])
-             if bool(np.asarray(node_state.valid)[victim]) else 0)
-    owner_freq = np.asarray(owner_state.freq)[np.asarray(slots)]
+    vfreq = (int(to_host(tracer, "freq", node_state.freq)[victim])
+             if bool(to_host(tracer, "valid", node_state.valid)[victim])
+             else 0)
+    owner_freq = to_host(tracer, "freq", owner_state.freq)[np.asarray(slots)]
     return owner_freq > vfreq
 
 
@@ -242,23 +247,25 @@ class CooperativeEdgeCluster:
 
     # ------------------------------------------------------------------
     def _admission_filter(self, node: int, owner: int, slots: np.ndarray,
-                          owner_state: SemanticCacheState) -> np.ndarray:
+                          owner_state: SemanticCacheState,
+                          tracer=NULL_TRACER) -> np.ndarray:
         """Which of ``slots`` (peer hits served by ``owner`` for ``node``)
         get re-admitted into ``node``'s shard, per ``cfg.admission``.
         ``owner_state`` is the owner shard as of the probe (pre-step
         snapshot in the grouped path)."""
         admit = admission_filter(
             self.cfg.admission, slots, owner_state, self.states[node],
-            self.cache.policy, self._peer_seen[node], (owner,))
+            self.cache.policy, self._peer_seen[node], (owner,), tracer)
         if (len(self._peer_seen[node])
                 > 4 * self.cfg.num_nodes * self.cfg.node_capacity):
-            self._prune_peer_seen(node)
+            self._prune_peer_seen(node, tracer)
         return admit
 
-    def _prune_peer_seen(self, node: int) -> None:
+    def _prune_peer_seen(self, node: int, tracer=NULL_TRACER) -> None:
         """Drop counters whose entry incarnation was evicted (its slot's
         inserted_at no longer matches) — bounds host memory under churn."""
-        ins = {p: np.asarray(s.inserted_at) for p, s in enumerate(self.states)}
+        ins = {p: to_host(tracer, "inserted_at", s.inserted_at)
+               for p, s in enumerate(self.states)}
         self._peer_seen[node] = {
             k: v for k, v in self._peer_seen[node].items()
             if int(ins[k[0]][k[1]]) == k[2]}
@@ -267,8 +274,8 @@ class CooperativeEdgeCluster:
     def serve_peer_hits(self, node: int, queries: jax.Array,
                         miss_rows: np.ndarray, g_idx: np.ndarray,
                         g_score: np.ndarray, hit, tier, owner, score, value,
-                        snapshot: Optional[List[SemanticCacheState]] = None
-                        ) -> int:
+                        snapshot: Optional[List[SemanticCacheState]] = None,
+                        tracer=NULL_TRACER) -> int:
         """Fold a cluster-wide probe of ``node``'s local misses into the
         result arrays: serve rows whose best global match is an
         above-threshold peer entry, touch the owners, apply admission.
@@ -287,6 +294,7 @@ class CooperativeEdgeCluster:
         evict/overwrite an owner slot a later group's probe result points
         into, and payloads must come from the probed state, not the
         mutated one.  Touches/admissions still apply to the live states.
+        Its host reads of shard state report to ``tracer``.
         """
         cfg = self.cfg
         probed = self.states if snapshot is None else snapshot
@@ -299,7 +307,7 @@ class CooperativeEdgeCluster:
             if not sel.any() or p == node:
                 continue
             rows = miss_rows[sel]
-            vals = np.asarray(probed[p].values)[slots[sel]]
+            vals = to_host(tracer, "value", probed[p].values)[slots[sel]]
             value[rows] = vals
             score[rows] = g_score[sel]
             tier[rows] = TIER_PEER
@@ -310,7 +318,8 @@ class CooperativeEdgeCluster:
             self.states[p] = self.cache.touch(
                 self.states[p], jnp.asarray(slots[sel]),
                 jnp.ones((int(sel.sum()),), bool))
-            admit = self._admission_filter(node, p, slots[sel], probed[p])
+            admit = self._admission_filter(node, p, slots[sel], probed[p],
+                                           tracer)
             if admit.any():
                 # de-duplicate entries within the batch: one admission per
                 # distinct cached entry (a sequential stream would hit the
@@ -336,7 +345,7 @@ class CooperativeEdgeCluster:
             mask = None if mask is None else np.asarray(mask, bool)[None]
         if mask is None:
             mask = np.ones(queries.shape[:3], bool)
-        pctx = build_probe_context([self])
+        pctx = build_probe_context([self], self.ladder.trace)
         res = self.ladder.probe(queries, mask, pctx,
                                 self.cfg.payload_dim,
                                 self.cfg.payload_dtype)

@@ -78,6 +78,7 @@ from repro.core.tiers import (TIER_LOCAL, TIER_MISS, TIER_PEER, TIER_NAMES,
                               empty_probe_arrays, route_flat)
 from repro.kernels.similarity import similarity_topk_batched
 from repro.obs.metrics import MetricsRegistry
+from repro.obs.trace import to_host
 from repro.parallel.sharding import (federated_digest_lookup,
                                      federated_digest_lookup_ivfpq,
                                      federated_digest_lookup_quantized)
@@ -238,8 +239,9 @@ class RemoteDigestRung:
 
         d_idx, d_score, admit = self._digest_probe(dq)
         dispatches = 1
-        d_idx = np.asarray(d_idx)[..., 0]
-        d_score = np.asarray(d_score)[..., 0]
+        tr = ctx.trace
+        d_idx = to_host(tr, "probe_idx", d_idx)[..., 0]
+        d_score = to_host(tr, "probe_score", d_score)[..., 0]
         cand = (d_idx // M).astype(np.int32)
 
         hit, tier, cluster, owner, score, value = empty_probe_arrays(
@@ -273,8 +275,8 @@ class RemoteDigestRung:
             jnp.asarray(aq), ctx.keys.reshape(K, N * C, D),
             ctx.valid.reshape(K, N * C), 1, impl=ccfg.lookup_impl)
         dispatches += 1
-        a_idx = np.asarray(a_idx)[..., 0]
-        a_score = np.asarray(a_score)[..., 0]
+        a_idx = to_host(tr, "probe_idx", a_idx)[..., 0]
+        a_score = to_host(tr, "probe_score", a_score)[..., 0]
 
         rebate = np.zeros((K, N), np.int64)
         values_of: Dict[Tuple[int, int], np.ndarray] = {}  # one pull per shard
@@ -294,8 +296,8 @@ class RemoteDigestRung:
                 p = int(a_idx[c, i]) // C
                 slot = int(a_idx[c, i]) % C
                 if (c, p) not in values_of:
-                    values_of[(c, p)] = np.asarray(
-                        ctx.pre_states[c][p].values)
+                    values_of[(c, p)] = to_host(
+                        tr, "value", ctx.pre_states[c][p].values)
                 hit[k, n, b] = True
                 tier[k, n, b] = TIER_REMOTE
                 cluster[k, n, b] = c
@@ -592,7 +594,7 @@ class FederatedEdgeTier:
         if self.membership is not None:
             # stamp membership events with the serving step they land on
             self.membership.step = self.step_count
-        pctx = build_probe_context(self.clusters)
+        pctx = build_probe_context(self.clusters, self.ladder.trace)
         res = self.ladder.probe(queries, mask, pctx,
                                 self.cfg.cluster.payload_dim,
                                 self.cfg.cluster.payload_dtype)
@@ -625,7 +627,7 @@ class FederatedEdgeTier:
             ok = admission_filter(
                 self.cfg.admission, slots, pre_states[c][p],
                 self.clusters[k].states[n], self.clusters[k].cache.policy,
-                seen, (n, c, p))
+                seen, (n, c, p), tracer=self.ladder.trace)
             if len(seen) > 4 * self.cfg.num_clusters * \
                     self.cfg.cluster.num_nodes \
                     * self.cfg.cluster.node_capacity:

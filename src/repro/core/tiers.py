@@ -51,7 +51,7 @@ import numpy as np
 
 from repro.kernels.similarity import similarity_topk_batched
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import NULL_TRACER, to_host
 
 TIER_LOCAL, TIER_PEER, TIER_REMOTE, TIER_MISS = 0, 1, 2, 3
 TIER_NAMES = ("local", "peer", "remote", "miss")
@@ -113,23 +113,25 @@ class ProbeContext:
     """Per-step shared state for the intra-org rungs: the pre-step shard
     snapshot every rung's probe and payload read resolves against (so an
     earlier rung's admissions never change what a later rung serves), plus
-    the stacked key/valid tensors the batched kernels scan."""
+    the stacked key/valid tensors the batched kernels scan, and the
+    tracer the rungs' host reads report to."""
 
     clusters: List                  # CooperativeEdgeCluster per cluster
     pre_states: List[List]          # (K, N) SemanticCacheState snapshot
     keys: jnp.ndarray               # (K, N, C, D)
     valid: jnp.ndarray              # (K, N, C)
     alive: List[List]               # (K, N) TTL-expiry masks
+    trace: Any                      # tracer of the org's ladder
 
 
-def build_probe_context(clusters: Sequence) -> ProbeContext:
+def build_probe_context(clusters: Sequence, tracer) -> ProbeContext:
     stacks = [cl._stacks() for cl in clusters]
     return ProbeContext(
         clusters=list(clusters),
         pre_states=[list(cl.states) for cl in clusters],
         keys=jnp.stack([s[0] for s in stacks]),
         valid=jnp.stack([s[1] for s in stacks]),
-        alive=[s[2] for s in stacks])
+        alive=[s[2] for s in stacks], trace=tracer)
 
 
 def empty_probe_arrays(queries: np.ndarray, payload_dim: int,
@@ -172,8 +174,10 @@ class LocalRung:
                 jnp.asarray(queries).reshape(K * N, B, D),
                 ctx.keys.reshape(K * N, C, D),
                 ctx.valid.reshape(K * N, C), 1, impl=cfg.lookup_impl)
-        l_idx = np.asarray(l_idx)[..., 0].reshape(K, N, B)
-        l_score = np.asarray(l_score)[..., 0].reshape(K, N, B)
+        tr = ctx.trace
+        l_idx = to_host(tr, "probe_idx", l_idx)[..., 0].reshape(K, N, B)
+        l_score = to_host(tr, "probe_score", l_score)[..., 0].reshape(
+            K, N, B)
 
         hit, tier, cluster, owner, score, value = empty_probe_arrays(
             queries, cfg.payload_dim, cfg.payload_dtype)
@@ -183,9 +187,9 @@ class LocalRung:
                     cl.states[g], jnp.asarray(l_idx[k, g]),
                     jnp.asarray(l_score[k, g]),
                     mask=jnp.asarray(mask[k, g]), alive=ctx.alive[k][g])
-                hit[k, g] = np.asarray(res.hit)
-                score[k, g] = np.asarray(res.score)
-                value[k, g] = np.asarray(res.value)
+                hit[k, g] = to_host(tr, "hit", res.hit)
+                score[k, g] = to_host(tr, "score", res.score)
+                value[k, g] = to_host(tr, "value", res.value)
             owner[k][hit[k]] = np.nonzero(hit[k])[0].astype(np.int32)
             cluster[k][hit[k]] = k
         tier[hit] = self.code
@@ -210,6 +214,7 @@ class PeerRung:
         C = cfg.node_capacity
         if not (cfg.share and N > 1 and mask.any()):
             return None
+        tr = ctx.trace
         if K == 1 and getattr(clusters[0], "mesh", None) is not None:
             # real cache-axis mesh: one shard_map collective (an all-gather
             # of (idx, score) per shard), same merged result
@@ -218,15 +223,17 @@ class PeerRung:
                 jnp.asarray(queries).reshape(N * B, D), ctx.keys[0],
                 ctx.valid[0], 1, clusters[0].mesh, clusters[0].cache_axis,
                 impl=cfg.lookup_impl)
-            g_idx = np.asarray(g_idx)[:, 0].reshape(K, N, B)
-            g_score = np.asarray(g_score)[:, 0].reshape(K, N, B)
+            g_idx = to_host(tr, "probe_idx", g_idx)[:, 0].reshape(K, N, B)
+            g_score = to_host(tr, "probe_score", g_score)[:, 0].reshape(
+                K, N, B)
         else:
             g_idx, g_score = similarity_topk_batched(
                 jnp.asarray(queries).reshape(K, N * B, D),
                 ctx.keys.reshape(K, N * C, D),
                 ctx.valid.reshape(K, N * C), 1, impl=cfg.lookup_impl)
-            g_idx = np.asarray(g_idx)[..., 0].reshape(K, N, B)
-            g_score = np.asarray(g_score)[..., 0].reshape(K, N, B)
+            g_idx = to_host(tr, "probe_idx", g_idx)[..., 0].reshape(K, N, B)
+            g_score = to_host(tr, "probe_score", g_score)[..., 0].reshape(
+                K, N, B)
 
         hit, tier, cluster, owner, score, value = empty_probe_arrays(
             queries, cfg.payload_dim, cfg.payload_dtype)
@@ -240,7 +247,7 @@ class PeerRung:
                     g, qk[g], miss_rows, g_idx[k, g][miss_rows],
                     g_score[k, g][miss_rows], hit[k, g], tier[k, g],
                     owner[k, g], score[k, g], value[k, g],
-                    snapshot=ctx.pre_states[k])
+                    snapshot=ctx.pre_states[k], tracer=tr)
                 if n_served:
                     cl.states[g] = dataclasses.replace(
                         cl.states[g],

@@ -8,7 +8,6 @@ import jax.numpy as jnp
 
 from repro.kernels.decode_attention.kernel import decode_attention_kernel
 from repro.kernels.decode_attention.ref import decode_attention_ref
-from repro.obs.profile import active, decode_attention_bytes, record_op
 
 
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -20,13 +19,7 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     """
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "ref"
-    fn = functools.partial(_decode_attention, impl=impl, block_kv=block_kv)
-    if active() is None:
-        return fn(q, k, v, kv_len)
-    B, S, K, D = (int(s) for s in k.shape)
-    return record_op(
-        "decode_attention", impl, fn, (q, k, v, kv_len),
-        decode_attention_bytes(B, S, K, D, k.dtype.itemsize))
+    return _decode_attention(q, k, v, kv_len, impl=impl, block_kv=block_kv)
 
 
 @functools.partial(jax.jit, static_argnames=("impl", "block_kv"))

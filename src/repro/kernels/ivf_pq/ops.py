@@ -1,9 +1,8 @@
 """Jit'd public wrapper for the two-stage IVF-PQ digest probe.
 
 Mirrors ``kernels/similarity/ops.py``: the public entry resolves
-``impl="auto"`` exactly once host-side, pads the query tile, and runs its
-jitted body through ``repro.obs.profile.record_op`` so profiled runs see
-``kernel/ivf_pq_probe/<resolved-impl>/...`` metrics (never ``auto``).
+``impl="auto"`` exactly once host-side and calls its jitted body, which
+pads the query tile.
 """
 from __future__ import annotations
 
@@ -15,7 +14,6 @@ import jax.numpy as jnp
 from repro.kernels.ivf_pq.kernel import ivf_pq_probe_kernel
 from repro.kernels.ivf_pq.ref import ivf_pq_probe_ref
 from repro.kernels.similarity.ops import resolve_impl
-from repro.obs.profile import active, ivf_pq_probe_bytes, record_op
 
 
 def ivf_pq_probe(queries: jax.Array, home: jax.Array, centroids: jax.Array,
@@ -34,18 +32,9 @@ def ivf_pq_probe(queries: jax.Array, home: jax.Array, centroids: jax.Array,
 
     impl: auto | pallas | pallas_interpret | ref
     """
-    impl = resolve_impl(impl)
-    fn = functools.partial(_ivf_pq_probe, k=k, n_probe=n_probe, impl=impl)
-    if active() is None:
-        return fn(queries, home, centroids, cent_valid, codes, slot_valid,
-                  slot_owner, codebook)
-    L, cap, S = (int(s) for s in codes.shape)
-    return record_op(
-        "ivf_pq_probe", impl, fn,
-        (queries, home, centroids, cent_valid, codes, slot_valid,
-         slot_owner, codebook),
-        ivf_pq_probe_bytes(int(queries.shape[0]), L, cap, S,
-                           int(queries.shape[1])))
+    return _ivf_pq_probe(queries, home, centroids, cent_valid, codes,
+                         slot_valid, slot_owner, codebook, k=k,
+                         n_probe=n_probe, impl=resolve_impl(impl))
 
 
 @functools.partial(jax.jit, static_argnames=("k", "n_probe", "impl"))

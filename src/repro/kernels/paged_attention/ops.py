@@ -27,7 +27,6 @@ import numpy as np
 
 from repro.kernels.paged_attention.kernel import paged_attention_kernel
 from repro.kernels.paged_attention.ref import paged_attention_ref
-from repro.obs.profile import active, record_op
 
 
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
@@ -45,22 +44,8 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     """
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "ref"
-    args = (q, k_pages, v_pages, block_table, lengths)
-    # profiling needs concrete lengths for the byte model — inside an outer
-    # jit (the engine's fused dispatches) lengths is a tracer and the call
-    # is part of a larger program anyway, so skip straight through
-    if active() is None or isinstance(lengths, jax.core.Tracer):
-        return _paged_attention(*args, impl=impl)
-    P, K, page, D = (int(s) for s in k_pages.shape)
-    modeled = attention_kv_bytes_per_step(
-        np.minimum(np.asarray(lengths) + int(q.shape[1]),
-                   page * int(block_table.shape[1])),
-        page_size=page, max_len=page * int(block_table.shape[1]),
-        kv_heads=K, head_dim=D, dtype_bytes=k_pages.dtype.itemsize,
-        impl="paged")
-    return record_op(
-        "paged_attention", impl,
-        functools.partial(_paged_attention, impl=impl), args, modeled)
+    return _paged_attention(q, k_pages, v_pages, block_table, lengths,
+                            impl=impl)
 
 
 @functools.partial(jax.jit, static_argnames=("impl",))

@@ -4,11 +4,8 @@ Selects the Pallas TPU kernel on TPU backends and the jnp oracle elsewhere
 (this container is CPU-only; the kernel is exercised via interpret=True in
 tests).  Handles padding to block multiples.
 
-Each public entry point resolves ``impl="auto"`` host-side, then runs its
-jitted body through ``repro.obs.profile.record_op`` — when a profiler is
-installed (``enable_profiling``) every call records blocked wall ms plus
-modeled HBM bytes under ``kernel/<op>/<impl>/...``; disabled (default) the
-cost is one module-global None check per call.
+Each public entry point resolves ``impl="auto"`` host-side, then calls its
+jitted body with the resolved name.
 """
 from __future__ import annotations
 
@@ -23,7 +20,6 @@ from repro.kernels.similarity.ref import (similarity_lookup_ref,
                                           similarity_topk_batched_ref,
                                           similarity_topk_ref,
                                           similarity_topk_touch_ref)
-from repro.obs.profile import active, record_op, similarity_bytes
 
 
 def _backend_is_tpu() -> bool:
@@ -38,10 +34,9 @@ def _resolve(impl: str) -> str:
 def resolve_impl(impl: str) -> str:
     """Resolve ``impl="auto"`` to the backend's concrete implementation.
 
-    Every profiled entry point (here, kernels/ivf_pq, parallel/sharding)
-    must call this exactly once in its host-side wrapper and pass the
-    resolved name down, so ``kernel/<op>/<impl>/...`` metrics never read
-    ``auto`` and the jitted inner never re-resolves at trace time.
+    Every entry point (here, kernels/ivf_pq, parallel/sharding) calls this
+    exactly once in its host-side wrapper and passes the resolved name
+    down, so the jitted inner never re-resolves at trace time.
     """
     return _resolve(impl)
 
@@ -56,15 +51,8 @@ def similarity_lookup(queries: jax.Array, keys: jax.Array, valid: jax.Array,
 
     impl: auto | pallas | pallas_interpret | ref
     """
-    impl = _resolve(impl)
-    fn = functools.partial(_similarity_lookup, impl=impl, block_q=block_q,
-                           block_c=block_c)
-    if active() is None:
-        return fn(queries, keys, valid)
-    return record_op(
-        "similarity_lookup", impl, fn, (queries, keys, valid),
-        similarity_bytes(int(queries.shape[0]), int(keys.shape[0]),
-                         int(queries.shape[1])))
+    return _similarity_lookup(queries, keys, valid, impl=_resolve(impl),
+                              block_q=block_q, block_c=block_c)
 
 
 @functools.partial(jax.jit, static_argnames=("impl", "block_q", "block_c"))
@@ -88,15 +76,8 @@ def similarity_topk(queries: jax.Array, keys: jax.Array, valid: jax.Array,
 
     impl: auto | pallas | pallas_interpret | ref
     """
-    impl = _resolve(impl)
-    fn = functools.partial(_similarity_topk, k=k, impl=impl,
-                           block_q=block_q, block_c=block_c)
-    if active() is None:
-        return fn(queries, keys, valid)
-    return record_op(
-        "similarity_topk", impl, fn, (queries, keys, valid),
-        similarity_bytes(int(queries.shape[0]), int(keys.shape[0]),
-                         int(queries.shape[1])))
+    return _similarity_topk(queries, keys, valid, k=k, impl=_resolve(impl),
+                            block_q=block_q, block_c=block_c)
 
 
 @functools.partial(jax.jit,
@@ -131,17 +112,9 @@ def similarity_topk_touch(queries: jax.Array, keys: jax.Array,
 
     impl: auto | pallas | pallas_interpret | ref
     """
-    impl = _resolve(impl)
-    fn = functools.partial(_similarity_topk_touch, k=k, threshold=threshold,
-                           impl=impl, block_c=block_c)
-    if active() is None:
-        return fn(queries, keys, valid, last_used, freq, clock, mask)
-    C = int(keys.shape[0])
-    return record_op(
-        "similarity_topk_touch", impl, fn,
-        (queries, keys, valid, last_used, freq, clock, mask),
-        similarity_bytes(int(queries.shape[0]), C,
-                         int(queries.shape[1]), meta_rows=C))
+    return _similarity_topk_touch(queries, keys, valid, last_used, freq,
+                                  clock, mask, k=k, threshold=threshold,
+                                  impl=_resolve(impl), block_c=block_c)
 
 
 @functools.partial(jax.jit,
@@ -187,15 +160,9 @@ def similarity_topk_batched(queries: jax.Array, keys: jax.Array,
 
     impl: auto | pallas | pallas_interpret | ref
     """
-    impl = _resolve(impl)
-    fn = functools.partial(_similarity_topk_batched, k=k, impl=impl,
-                           block_q=block_q, block_c=block_c)
-    if active() is None:
-        return fn(queries, keys, valid)
-    N, Q, D = (int(s) for s in queries.shape)
-    return record_op(
-        "similarity_topk_batched", impl, fn, (queries, keys, valid),
-        similarity_bytes(N * Q, N * int(keys.shape[1]), D))
+    return _similarity_topk_batched(queries, keys, valid, k=k,
+                                    impl=_resolve(impl), block_q=block_q,
+                                    block_c=block_c)
 
 
 @functools.partial(jax.jit,

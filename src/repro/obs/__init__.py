@@ -1,18 +1,17 @@
-"""Observability: tracing (Perfetto export), the unified metrics registry,
-and kernel profiling hooks.  See docs/observability.md."""
+"""Observability: tracing (Perfetto export or the JAX profiler's trace),
+the spanned device-to-host read, and the unified metrics registry.  See
+docs/observability.md."""
 from repro.obs.metrics import (Counter, CounterDict, Gauge, Histogram,
                                LazyCounterGroup, MetricsRegistry)
-from repro.obs.profile import (KernelProfiler, active, disable_profiling,
-                               enable_profiling)
 from repro.obs.trace import (NULL_TRACER, PID_ENGINE, PID_REQUESTS,
-                             NullTracer, Tracer)
+                             NullTracer, ProfilerTracer, Tracer, to_host)
 from repro.obs.views import (EMPTY_DIGEST_STATS, digest_block, ladder_block,
                              org_stats)
 
 __all__ = [
     "Counter", "CounterDict", "Gauge", "Histogram", "LazyCounterGroup",
     "MetricsRegistry",
-    "KernelProfiler", "active", "disable_profiling", "enable_profiling",
-    "NULL_TRACER", "PID_ENGINE", "PID_REQUESTS", "NullTracer", "Tracer",
+    "NULL_TRACER", "PID_ENGINE", "PID_REQUESTS", "NullTracer",
+    "ProfilerTracer", "Tracer", "to_host",
     "EMPTY_DIGEST_STATS", "digest_block", "ladder_block", "org_stats",
 ]

@@ -1,117 +1,29 @@
-"""Kernel profiling hooks — per-call wall ms + modeled bytes per Pallas op.
+"""Modeled HBM bytes of the similarity-family probes.
 
-Each public kernel entry point (``similarity_topk*`` /
-``similarity_lookup`` in ``kernels/similarity/ops.py``,
-``paged_attention``, ``decode_attention``) calls ``record_op`` around its
-jitted dispatch when a profiler is installed.  The record carries:
-
-* measured wall ms of the dispatch (``block_until_ready`` included — the
-  number a roofline compares against), and
-* the op's MODELED HBM bytes, from the same byte models the benchmarks
-  quote (``paged_attention.attention_kv_bytes_per_step`` for the
-  attention ops; ``similarity_bytes``/``digest_probe_bytes`` below for
-  the similarity probes, the latter reusing ``DigestConfig.row_bytes``'s
-  int8-vs-fp32 wire model),
-
-tagged by impl (``pallas`` | ``pallas_interpret`` | ``ref``), into the
-installed registry:
-
-    kernel/<op>/<impl>/calls           Counter
-    kernel/<op>/<impl>/wall_ms         Histogram (p50/p95/p99)
-    kernel/<op>/<impl>/modeled_bytes   Counter (cumulative)
-
-which gives every benchmark a measured-vs-modeled column for free:
-``bytes / (wall_ms / 1e3)`` is achieved bandwidth, modeled bytes over the
-hardware's peak is the roofline floor.
-
-Disabled (the default) the hot path pays ONE module-global ``is None``
-check per op call.  Ops called *inside* an outer jit (the engine's fused
-decode/prefill dispatches trace ``paged_attention`` as part of their own
-program) are skipped automatically — a traced array has no wall time to
-measure — so enabling profiling never breaks tracing; the engine-level
-dispatch spans cover those fused calls instead.
+``similarity_bytes`` is one probe's streaming read of its key matrix;
+``digest_probe_bytes`` the same over the region board's digest replicas
+in their wire format (``DigestConfig.row_bytes``'s int8-vs-fp32 model);
+``ivf_pq_probe_bytes`` the two-stage IVF-PQ probe over packed code
+lists.  ``benchmarks/ann_probe.py`` compares the three per advertised
+row.  Kernel time comes from the device trace (``jax.profiler``), not
+from here.
 """
 from __future__ import annotations
 
-import time
 from typing import Optional
-
-import jax
-import numpy as np
-
-from repro.obs.metrics import MetricsRegistry
-
-_PROFILER: Optional["KernelProfiler"] = None
-
-
-class KernelProfiler:
-    def __init__(self, metrics: MetricsRegistry):
-        self.metrics = metrics
-
-    def record(self, op: str, impl: str, wall_ms: float,
-               modeled_bytes: float) -> None:
-        base = f"kernel/{op}/{impl}"
-        self.metrics.counter(f"{base}/calls").inc()
-        self.metrics.histogram(f"{base}/wall_ms").observe(wall_ms)
-        self.metrics.counter(f"{base}/modeled_bytes").inc(
-            int(modeled_bytes))
-
-
-def enable_profiling(metrics: MetricsRegistry) -> KernelProfiler:
-    """Install a profiler recording into ``metrics``; returns it."""
-    global _PROFILER
-    _PROFILER = KernelProfiler(metrics)
-    return _PROFILER
-
-
-def disable_profiling() -> None:
-    global _PROFILER
-    _PROFILER = None
-
-
-def active() -> Optional[KernelProfiler]:
-    return _PROFILER
-
-
-def _is_tracing(*arrays) -> bool:
-    return any(isinstance(a, jax.core.Tracer) for a in arrays)
-
-
-def record_op(op: str, impl: str, fn, args, modeled_bytes: float):
-    """Run ``fn(*args)`` and, when a profiler is installed and we are NOT
-    inside an outer jit trace, record its blocked wall time + modeled
-    bytes.  Returns ``fn``'s result either way."""
-    prof = _PROFILER
-    if prof is None or _is_tracing(*args):
-        return fn(*args)
-    t0 = time.perf_counter()
-    out = fn(*args)
-    jax.block_until_ready(out)
-    prof.record(op, impl, (time.perf_counter() - t0) * 1e3, modeled_bytes)
-    return out
-
-
-# ---------------------------------------------------------------------------
-# Byte models for the similarity-family ops (the attention ops reuse
-# kernels/paged_attention.attention_kv_bytes_per_step)
-# ---------------------------------------------------------------------------
 
 
 def similarity_bytes(n_queries: int, n_keys: int, dim: int,
-                     key_bytes_per_row: Optional[float] = None,
-                     meta_rows: int = 0) -> float:
+                     key_bytes_per_row: Optional[float] = None) -> float:
     """Modeled HBM traffic of one similarity probe: one read of the query
     block, one streaming read of the key matrix (+ validity byte per row),
     and the (Q, k) outputs (negligible, ignored).  ``key_bytes_per_row``
     overrides the fp32 ``dim * 4`` key row (the int8 digest probe passes
-    ``DigestConfig.row_bytes``'s ``dim + 4``).  ``meta_rows`` adds the
-    fused-touch epilogue's read+write of two int32 metadata words per
-    cache row."""
+    ``DigestConfig.row_bytes``'s ``dim + 4``)."""
     row = (dim * 4.0 if key_bytes_per_row is None
            else float(key_bytes_per_row))
     return (n_queries * dim * 4.0            # query block read
-            + n_keys * (row + 1.0)           # key rows + valid bytes
-            + meta_rows * 2 * 4.0 * 2)       # last_used+freq, read+write
+            + n_keys * (row + 1.0))          # key rows + valid bytes
 
 
 def digest_probe_bytes(n_queries: int, num_clusters: int, digest_size: int,
@@ -138,24 +50,3 @@ def ivf_pq_probe_bytes(n_queries: int, n_lists: int, list_cap: int,
             + n_lists * (dim * 4.0 + 1.0)              # centroids + valid
             + n_sub * 256 * (dim // n_sub) * 4.0       # shared codebook
             + n_lists * list_cap * (n_sub + 2.0))      # codes+valid+owner
-
-
-def attention_bytes(kv_len, *, page_size: int, max_len: int, kv_heads: int,
-                    head_dim: int, dtype_bytes: int, impl: str) -> float:
-    """Convenience re-export of the paged-attention byte model so profile
-    callers need one import (lazy to avoid a kernels<->obs import cycle at
-    module load)."""
-    from repro.kernels.paged_attention import attention_kv_bytes_per_step
-    return attention_kv_bytes_per_step(
-        kv_len, page_size=page_size, max_len=max_len, kv_heads=kv_heads,
-        head_dim=head_dim, dtype_bytes=dtype_bytes, impl=impl)
-
-
-def decode_attention_bytes(batch: int, seq: int, kv_heads: int,
-                           head_dim: int, dtype_bytes: int) -> float:
-    """Modeled k+v read of one dense flash-decode dispatch: every row
-    streams its full (S, K, D) k and v once."""
-    return float(2 * batch * seq * kv_heads * head_dim * dtype_bytes)
-
-
-_ = np  # numpy reserved for future byte models; keeps the import explicit

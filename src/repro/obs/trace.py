@@ -1,4 +1,5 @@
-"""Zero-dep span tracer — Chrome trace-event JSON, loadable in Perfetto.
+"""Span tracers — Chrome trace-event JSON for Perfetto, or the JAX
+profiler's own trace.
 
 Two tracks, one timeline (microseconds since the tracer's epoch):
 
@@ -6,9 +7,13 @@ Two tracks, one timeline (microseconds since the tracer's epoch):
   pipeline, emitted as matched ``B``/``E`` duration events that nest on
   the engine tid — ``step`` > { ``schedule`` > [``descriptor``,
   ``lookup`` > per-rung ``probe:local|peer|remote|cloud``],
-  ``admit`` > [``prefill``, ``prefill_chunk``], ``decode``, ``retire`` } —
+  ``admit`` > [``prefill``, ``prefill_chunk``, ``chunk_prep``],
+  ``decode``, ``emit`` > ``retire``, ``h2d:tokens`` } —
   plus a ``request:<rid>`` span (category ``request``) inside the step
   that served/retired the request, carrying tier + completion args.
+  Every host read of a device value on the served path is a
+  ``d2h:<what>`` span (``to_host``), and the step's uploads are
+  ``h2d:<what>`` spans, each inside the phase that makes it.
 
 * **request track** (``pid=PID_REQUESTS``, one tid per request id):
   MODELED-latency spans on the paced clock, emitted as ``X`` complete
@@ -26,7 +31,10 @@ check (``if self.trace.enabled:``) before skipping span bookkeeping.
 
 Export: ``Tracer.export(path)`` writes ``{"traceEvents": [...]}`` —
 open in https://ui.perfetto.dev (or chrome://tracing).  Validation lives
-in ``scripts/check_trace.py``.
+in ``scripts/check_trace.py``.  ``ProfilerTracer`` records the engine
+track as ``jax.profiler.TraceAnnotation``s instead, so a device trace
+taken with ``jax.profiler`` shows what the host was doing beside the
+device's operations.
 """
 from __future__ import annotations
 
@@ -34,6 +42,8 @@ import json
 import time
 from collections import deque
 from typing import Dict, List, Optional, Tuple
+
+import jax
 
 PID_ENGINE = 1
 PID_REQUESTS = 2
@@ -246,3 +256,42 @@ class Tracer(NullTracer):
         with open(path, "w") as f:
             json.dump({"traceEvents": self.events + tail,
                        "displayTimeUnit": "ms"}, f)
+
+
+class ProfilerTracer(NullTracer):
+    """Spans on the JAX profiler's clock: each span is a
+    ``jax.profiler.TraceAnnotation`` named as the engine names it, its
+    ``args`` the annotation's keyword arguments.  The spans land in the
+    trace ``jax.profiler.start_trace`` writes, on the host plane beside
+    the device's operations.  The modeled request timelines are not
+    times and are dropped."""
+
+    enabled = True
+
+    def __init__(self):
+        self._open: List[jax.profiler.TraceAnnotation] = []
+
+    def begin(self, name, *, cat="engine", pid=PID_ENGINE, tid=0, ts=None,
+              args=None):
+        ann = jax.profiler.TraceAnnotation(name, **(args or {}))
+        ann.__enter__()
+        self._open.append(ann)
+
+    def end(self, *, pid=PID_ENGINE, tid=0, ts=None):
+        self._open.pop().__exit__(None, None, None)
+
+    def span(self, name, *, cat="engine", pid=PID_ENGINE, tid=0, args=None):
+        return jax.profiler.TraceAnnotation(name, **(args or {}))
+
+    def request_timeline(self, *args, **kwargs) -> None:
+        pass
+
+
+def to_host(tracer, what: str, x):
+    """``jax.device_get(x)``: the one way the served path reads a device
+    value on the host.  The read waits for the device, so when ``tracer``
+    records it is a ``d2h:<what>`` span."""
+    if not tracer.enabled:
+        return jax.device_get(x)
+    with tracer.span("d2h:" + what, cat="sync"):
+        return jax.device_get(x)

@@ -72,8 +72,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.obs.profile import (active, digest_probe_bytes, ivf_pq_probe_bytes,
-                               record_op)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -311,20 +309,12 @@ def federated_digest_lookup(queries: jax.Array, digests: jax.Array,
     probing digests instead of shards.
 
     Host wrapper: ``impl="auto"`` resolves exactly ONCE here (never inside
-    the trace) and, when a profiler is installed, the dispatch records
-    under ``kernel/federated_digest_lookup/<resolved-impl>/...`` with the
-    ``digest_probe_bytes`` wire model.
+    the trace).
     """
     from repro.kernels.similarity.ops import resolve_impl
 
-    impl = resolve_impl(impl)
-    fn = partial(_federated_digest_lookup, k=k, impl=impl)
-    if active() is None:
-        return fn(queries, digests, valid)
-    K, M, D = (int(s) for s in digests.shape)
-    return record_op(
-        "federated_digest_lookup", impl, fn, (queries, digests, valid),
-        digest_probe_bytes(int(queries.shape[1]), K, M, D, "fp32"))
+    return _federated_digest_lookup(queries, digests, valid, k=k,
+                                    impl=resolve_impl(impl))
 
 
 @partial(jax.jit, static_argnames=("k", "impl"))
@@ -349,20 +339,12 @@ def federated_digest_lookup_quantized(queries: jax.Array, codes: jax.Array,
     (``core/digest.py::DigestPublisher``), kept int8-resident and
     dequantized inside this one jitted dispatch.  queries/valid/k as in
     ``federated_digest_lookup``; same home-cluster masking, same kernel,
-    same resolve-once + ``record_op`` host wrapper (modeled with the int8
-    ``D + 4`` row).
+    same resolve-once host wrapper.
     """
     from repro.kernels.similarity.ops import resolve_impl
 
-    impl = resolve_impl(impl)
-    fn = partial(_federated_digest_lookup_quantized, k=k, impl=impl)
-    if active() is None:
-        return fn(queries, codes, scales, valid)
-    K, M, D = (int(s) for s in codes.shape)
-    return record_op(
-        "federated_digest_lookup_quantized", impl, fn,
-        (queries, codes, scales, valid),
-        digest_probe_bytes(int(queries.shape[1]), K, M, D, "int8"))
+    return _federated_digest_lookup_quantized(queries, codes, scales, valid,
+                                              k=k, impl=resolve_impl(impl))
 
 
 @partial(jax.jit, static_argnames=("k", "impl"))
@@ -394,20 +376,12 @@ def federated_digest_lookup_ivfpq(queries: jax.Array, index, k: int = 1, *,
     """
     from repro.kernels.similarity.ops import resolve_impl
 
-    impl = resolve_impl(impl)
-    fn = partial(_federated_digest_lookup_ivfpq, k=k, n_probe=n_probe,
-                 impl=impl)
-    args = (queries, jnp.asarray(index.centroids),
-            jnp.asarray(index.cent_valid), jnp.asarray(index.codes),
-            jnp.asarray(index.slot_valid), jnp.asarray(index.slot_owner),
-            jnp.asarray(index.codebook), jnp.asarray(index.slot_rid))
-    if active() is None:
-        return fn(*args)
-    K, B, D = (int(s) for s in queries.shape)
-    L, cap, S = (int(s) for s in index.codes.shape)
-    return record_op(
-        "federated_digest_lookup_ivfpq", impl, fn, args,
-        ivf_pq_probe_bytes(K * B, L, cap, S, D))
+    return _federated_digest_lookup_ivfpq(
+        queries, jnp.asarray(index.centroids),
+        jnp.asarray(index.cent_valid), jnp.asarray(index.codes),
+        jnp.asarray(index.slot_valid), jnp.asarray(index.slot_owner),
+        jnp.asarray(index.codebook), jnp.asarray(index.slot_rid), k=k,
+        n_probe=n_probe, impl=resolve_impl(impl))
 
 
 @partial(jax.jit, static_argnames=("k", "n_probe", "impl"))

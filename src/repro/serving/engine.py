@@ -91,7 +91,7 @@ from repro.core.router import (DeadlineStats, LatencyBreakdown, PayloadSizes,
 from repro.core.tiers import (TIER_LOCAL, TIER_MISS, TIER_NAMES, TIER_PEER,
                               TIER_REMOTE, pow2 as _pow2, route_flat)
 from repro.obs.metrics import CounterDict, LazyCounterGroup, MetricsRegistry
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import NULL_TRACER, to_host
 from repro.obs.views import digest_block, ladder_block, org_stats
 from repro.serving.kv_cache import (PagedKVCache, batch_cache_scatter,
                                     init_batch_cache, init_paged_pool)
@@ -217,7 +217,7 @@ class ServedResult:
     req_id: int
     tokens: np.ndarray
     source: str                      # edge | peer | remote | cloud
-    latency_s: float                 # hits: modeled; cloud: submit->retire
+    latency_s: float                 # submit -> result, measured wall s
     decode_steps: int
     breakdown: Optional[LatencyBreakdown] = None   # modeled terms (hits)
     priority: int = 0
@@ -321,23 +321,31 @@ class ServingEngine:
             "engine/prefill_tokens_shared")
         self._truncated: set = set()
 
+        # every jit is a named function: the name is the program's name
+        # in a device trace, so each operation says which layer it serves
         self._decode = jax.jit(model.decode_step, donate_argnums=(1,))
-        self._prefill = jax.jit(
-            lambda p, t, ln: model.prefill(p, t, max_len=cfg.max_len,
-                                           lengths=ln))
+
+        def prefill(p, t, ln):
+            return model.prefill(p, t, max_len=cfg.max_len, lengths=ln)
+
+        self._prefill = jax.jit(prefill)
         if self._paged:
             # map the serving-level knob onto the kernel wrapper's impl
             # strings; "gather" keeps the dense-view oracle path
             _impl = {"gather": "gather", "paged": "auto",
                      "paged_interpret": "pallas_interpret"}[cfg.attn_impl]
-            self._chunk_paged = jax.jit(
-                lambda p, t, c, ln, w, bt: model.prefill_chunk(
-                    p, t, c, ln, w, block_table=bt, attn_impl=_impl),
-                donate_argnums=(2,))
-            self._decode_paged = jax.jit(
-                lambda p, c, t, ln, bt: model.decode_step(
-                    p, c, t, ln, block_table=bt, attn_impl=_impl),
-                donate_argnums=(1,))
+
+            def prefill_chunk_paged(p, t, c, ln, w, bt):
+                return model.prefill_chunk(p, t, c, ln, w, block_table=bt,
+                                           attn_impl=_impl)
+
+            def decode_paged(p, c, t, ln, bt):
+                return model.decode_step(p, c, t, ln, block_table=bt,
+                                         attn_impl=_impl)
+
+            self._chunk_paged = jax.jit(prefill_chunk_paged,
+                                        donate_argnums=(2,))
+            self._decode_paged = jax.jit(decode_paged, donate_argnums=(1,))
         # chunked prefill needs linear caches: SWA rings rotate by padded
         # length and recurrent conv/state prefill absorbs pads, so those
         # models keep the exact one-shot path (prefill_chunk is ignored)
@@ -349,9 +357,10 @@ class ServingEngine:
             # (1, prefill_chunk) shape with the true width passed as data,
             # so the tail chunk of any prompt length reuses one compile
             # instead of retracing per remainder width
-            self._chunk_fn = jax.jit(
-                lambda p, t, c, ln, w: model.prefill_chunk(p, t, c, ln, w),
-                donate_argnums=(2,))
+            def prefill_chunk(p, t, c, ln, w):
+                return model.prefill_chunk(p, t, c, ln, w)
+
+            self._chunk_fn = jax.jit(prefill_chunk, donate_argnums=(2,))
 
         # CoIC front: one ladder org (core/tiers.py) — a cooperative
         # cluster (1-node for the solo cache) or a cross-cluster federation
@@ -370,11 +379,16 @@ class ServingEngine:
             if c.descriptor == "prefix":
                 self._descriptor = PrefixDescriptor(model, k_layers=c.k_layers)
                 key_dim = model.cfg.d_model
-                self._desc_fn = jax.jit(lambda p, t: self._descriptor(p, t))
+
+                def descriptor(p, t):
+                    return self._descriptor(p, t)
             else:
                 sk = NgramSketchDescriptor(dim=c.descriptor_dim)
                 key_dim = c.descriptor_dim
-                self._desc_fn = jax.jit(lambda p, t: sk(t))
+
+                def descriptor(p, t):
+                    return sk(t)
+            self._desc_fn = jax.jit(descriptor)
             self.key_dim = key_dim
             cluster_cfg = ClusterConfig(
                 num_nodes=c.num_nodes, node_capacity=c.capacity,
@@ -641,7 +655,8 @@ class ServingEngine:
         if tr.enabled:
             tr.end()
         self.dispatches["descriptor"] += 1
-        return np.asarray(desc)[:len(prompts)], (time.perf_counter() - t0) * 1e3
+        return (to_host(tr, "descriptor", desc)[:len(prompts)],
+                (time.perf_counter() - t0) * 1e3)
 
     # ------------------------------------------------------------------
     def _schedule(self) -> None:
@@ -715,7 +730,7 @@ class ServingEngine:
                     peer_net_ms=(self.router.peer_broadcast_ms(lm[clu])
                                  if t == TIER_REMOTE and self._peer_on
                                  else 0.0))
-                self._t_submit.pop(rid, None)
+                t_sub = self._t_submit.pop(rid)
                 lat.deadline_ms = self._deadline.get(rid)
                 modeled_ms = lat.total_ms
                 skip = ()
@@ -727,7 +742,8 @@ class ServingEngine:
                     modeled_ms -= lat.descriptor_ms + lat.lookup_ms
                     skip = ("descriptor_ms", "lookup_ms")
                 self._finalize(rid, tokens=toks, source=src,
-                               latency_s=lat.total_ms / 1e3, decode_steps=0,
+                               latency_s=time.perf_counter() - t_sub,
+                               decode_steps=0,
                                breakdown=lat, modeled_ms=modeled_ms,
                                wall_s=lat.total_ms / 1e3,
                                terms=(_latency_terms(lat, skip)
@@ -816,11 +832,16 @@ class ServingEngine:
             self.cache = batch_cache_scatter(
                 self.cache, {k: v[:, :m] for k, v in many_cache.items()},
                 jnp.asarray(slots, jnp.int32))
-            nxt = np.asarray(jnp.argmax(logits, -1).astype(jnp.int32))[:m]
+            nxt = to_host(tr, "argmax",
+                          jnp.argmax(logits, -1).astype(jnp.int32))[:m]
+            if tr.enabled:
+                tr.begin("h2d:row_state", cat="sync")
             self.lengths = self.lengths.at[jnp.asarray(slots)].set(
                 jnp.asarray(lens))
             self.tokens = self.tokens.at[jnp.asarray(slots)].set(
                 jnp.asarray(nxt))
+            if tr.enabled:
+                tr.end()
             now = time.perf_counter()
             for i, ((rid, prompt), slot) in enumerate(zip(taken, slots)):
                 self.row_active[slot] = True
@@ -864,6 +885,10 @@ class ServingEngine:
         index."""
         if not self.chunking:
             return
+        tr = self.trace
+        if tr.enabled:
+            tr.begin("chunk_prep", cat="engine",
+                     args={"rows": len(self.chunking)})
         sts = sorted(self.chunking.values(),
                      key=lambda st: self._queue_key((st.req_id,)))
         C = self._chunk_width
@@ -879,18 +904,22 @@ class ServingEngine:
             lens[i] = st.filled
             widths[i] = n
             bt[i] = self.kv.block_table[st.slot]
-        tr = self.trace
         if tr.enabled:
+            tr.end()
             tr.begin("prefill_chunk", cat="engine",
                      args={"rows": len(sts), "width": C})
+            tr.begin("h2d:chunk", cat="sync")
+        toks_d, lens_d = jnp.asarray(toks), jnp.asarray(lens)
+        widths_d, bt_d = jnp.asarray(widths), jnp.asarray(bt)
+        if tr.enabled:
+            tr.end()
         logits, self.cache, _ = self._chunk_paged(
-            self.params, jnp.asarray(toks), self.cache, jnp.asarray(lens),
-            jnp.asarray(widths), jnp.asarray(bt))
+            self.params, toks_d, self.cache, lens_d, widths_d, bt_d)
         if tr.enabled:
             tr.end()
         self.dispatches["prefill_chunk"] += 1
         self.prefill_tokens_computed += int(widths.sum())
-        nxt = np.asarray(jnp.argmax(logits, -1), np.int32)
+        nxt = to_host(tr, "argmax", jnp.argmax(logits, -1))
         now = time.perf_counter()
         for i, st in enumerate(sts):
             st.filled += int(widths[i])
@@ -899,8 +928,12 @@ class ServingEngine:
             rid, slot = st.req_id, st.slot
             del self.chunking[rid]
             self.kv.register(slot, st.prompt, from_page=st.shared_pages)
+            if tr.enabled:
+                tr.begin("h2d:row_state", cat="sync")
             self.lengths = self.lengths.at[slot].set(len(st.prompt))
             self.tokens = self.tokens.at[slot].set(int(nxt[i]))
+            if tr.enabled:
+                tr.end()
             self.row_active[slot] = True
             self.active[slot] = _Active(req_id=rid, slot=slot,
                                         generated=[int(nxt[i])],
@@ -949,10 +982,14 @@ class ServingEngine:
         if tr.enabled:
             tr.begin("prefill_chunk", cat="engine",
                      args={"rid": st.req_id, "width": n})
+            tr.begin("h2d:chunk", cat="sync")
+        chunk_d = jnp.asarray(chunk)
+        filled_d = jnp.asarray([st.filled], jnp.int32)
+        n_d = jnp.asarray([n], jnp.int32)
+        if tr.enabled:
+            tr.end()
         logits, st.cache, _ = self._chunk_fn(
-            self.params, jnp.asarray(chunk), st.cache,
-            jnp.asarray([st.filled], jnp.int32),
-            jnp.asarray([n], jnp.int32))
+            self.params, chunk_d, st.cache, filled_d, n_d)
         if tr.enabled:
             tr.end()
         self.dispatches["prefill_chunk"] += 1
@@ -964,10 +1001,14 @@ class ServingEngine:
         del self.chunking[rid]
         self.cache = batch_cache_scatter(
             self.cache, st.cache, jnp.asarray([slot], jnp.int32))
-        nxt = int(jnp.argmax(logits[0]))
+        nxt = int(to_host(tr, "argmax", jnp.argmax(logits[0])))
         L = len(st.prompt)
+        if tr.enabled:
+            tr.begin("h2d:row_state", cat="sync")
         self.lengths = self.lengths.at[slot].set(L)
         self.tokens = self.tokens.at[slot].set(nxt)
+        if tr.enabled:
+            tr.end()
         self.row_active[slot] = True
         self.active[slot] = _Active(req_id=rid, slot=slot, generated=[nxt],
                                     t_admit=time.perf_counter())
@@ -1063,26 +1104,37 @@ class ServingEngine:
             # mid-prefill and free rows ride the batched decode with an
             # all-INVALID table row: their junk write drops instead of
             # landing in a live or half-filled page
+            if tr.enabled:
+                tr.begin("h2d:decode_table", cat="sync")
+            bt = jnp.asarray(self.kv.decode_table(self.row_active))
+            if tr.enabled:
+                tr.end()
             logits, self.cache, self.lengths = self._decode_paged(
-                self.params, self.cache, self.tokens, self.lengths,
-                jnp.asarray(self.kv.decode_table(self.row_active)))
+                self.params, self.cache, self.tokens, self.lengths, bt)
         else:
             logits, self.cache, self.lengths = self._decode(
                 self.params, self.cache, self.tokens, self.lengths)
         self.dispatches["decode"] += 1
-        nxt = np.asarray(jnp.argmax(logits, -1), np.int32)
+        nxt = to_host(tr, "argmax", jnp.argmax(logits, -1))
         self._decode_ms.observe((time.perf_counter() - t0) * 1e3)
         if tr.enabled:
             tr.end()
+            tr.begin("emit", cat="engine", args={"rows": len(self.active)})
         for slot in list(self.active):
             a = self.active[slot]
             a.generated.append(int(nxt[slot]))
             done = (len(a.generated) >= self.cfg.max_new_tokens
                     or (self.cfg.eos_id >= 0 and nxt[slot] == self.cfg.eos_id)
-                    or int(self.lengths[slot]) >= self.cfg.max_len - 1)
+                    or int(to_host(tr, "length", self.lengths[slot]))
+                    >= self.cfg.max_len - 1)
             if done:
                 self._retire(slot)
+        if tr.enabled:
+            tr.end()
+            tr.begin("h2d:tokens", cat="sync")
         self.tokens = jnp.asarray(nxt)
+        if tr.enabled:
+            tr.end()
 
     def run_until_drained(self, max_steps: int = 10_000) -> List[ServedResult]:
         steps = 0

@@ -12,9 +12,7 @@ The telemetry contract (src/repro/obs/, docs/observability.md):
     bit-identical and the registry holds the same metric names (tracing
     adds spans, never metrics);
   * the per-step dispatch bounds (engine <= 2, federated ladder <= 4)
-    re-pin straight from the registry snapshot;
-  * kernel profiling hooks record per-call wall ms + modeled bytes under
-    ``kernel/<op>/<impl>/...`` only while enabled.
+    re-pin straight from the registry snapshot.
 """
 import dataclasses
 import json
@@ -214,6 +212,38 @@ def test_null_tracer_is_inert():
         tr.end()                  # nothing open
 
 
+def test_profiler_tracer_annotates_the_profilers_trace(tmp_path):
+    """``ProfilerTracer`` spans land in the JAX profiler's trace under the
+    engine's names, with their args as the events' stats; the modeled
+    request timelines are dropped."""
+    import glob
+
+    import jax.numpy as jnp
+
+    from repro.obs.trace import ProfilerTracer, to_host
+
+    tr = ProfilerTracer()
+    assert tr.enabled
+    jax.profiler.start_trace(str(tmp_path))
+    tr.begin("step", args={"step": 3})
+    with tr.span("decode", args={"active": 4}):
+        x = jnp.arange(4) + 1
+    assert int(to_host(tr, "argmax", x)[3]) == 4
+    tr.request_timeline(0, ts_ms=0.0, tier="edge", terms=[("uplink", 1.0)],
+                        completion_ms=1.0)
+    tr.end()
+    jax.profiler.stop_trace()
+    path, = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    events = {ev.name: dict(ev.stats)
+              for plane in jax.profiler.ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for ev in line.events}
+    assert events["step"] == {"step": 3}
+    assert events["decode"] == {"active": 4}
+    assert "d2h:argmax" in events
+    assert not {"request", "uplink"} & set(events)
+
+
 # ---------------------------------------------------------------------------
 # dispatch bounds re-pinned from the registry snapshot
 # ---------------------------------------------------------------------------
@@ -228,87 +258,14 @@ def test_dispatch_bounds_hold_in_registry(obs_runs):
 
 
 # ---------------------------------------------------------------------------
-# kernel profiling hooks
+# probe byte models
 # ---------------------------------------------------------------------------
 
 
-def test_kernel_profiler_records_only_while_enabled():
-    from repro.kernels.similarity.ops import similarity_lookup
-    from repro.obs.profile import (active, disable_profiling,
-                                   enable_profiling)
-
-    q = np.eye(8, dtype=np.float32)[:2]
-    keys = np.eye(8, dtype=np.float32)
-    valid = np.ones(8, dtype=bool)
-    assert active() is None
-    m = MetricsRegistry()
-    enable_profiling(m)
-    try:
-        idx, score = similarity_lookup(q, keys, valid)
-        assert m.value("kernel/similarity_lookup/ref/calls") == 1
-        assert m.value("kernel/similarity_lookup/ref/wall_ms")["sum"] > 0
-        assert m.value("kernel/similarity_lookup/ref/modeled_bytes") > 0
-    finally:
-        disable_profiling()
-    assert active() is None
-    similarity_lookup(q, keys, valid)
-    assert m.value("kernel/similarity_lookup/ref/calls") == 1   # unchanged
-    np.testing.assert_array_equal(np.asarray(idx), [0, 1])
-
-
-def test_digest_lookups_profile_under_resolved_impl():
-    """The digest probes resolve impl="auto" ONCE in their host wrapper
-    and record the dispatch themselves — metric names carry the resolved
-    impl (never "auto"), and the probe is no longer invisible to the
-    profiler just because its body is jitted."""
-    import jax.numpy as jnp
-
-    from repro.core.digest import (build_ivfpq_index, quantize_rows,
-                                   train_pq_codebook)
-    from repro.obs.profile import disable_profiling, enable_profiling
-    from repro.parallel.sharding import (federated_digest_lookup,
-                                         federated_digest_lookup_ivfpq,
-                                         federated_digest_lookup_quantized)
-
-    rng = np.random.default_rng(0)
-    K, M, D = 2, 16, 16
-    keys = rng.standard_normal((K, M, D)).astype(np.float32)
-    keys /= np.linalg.norm(keys, axis=-1, keepdims=True)
-    valid = np.ones((K, M), bool)
-    q = keys[:, :4]                                     # (K, 4, D)
-
-    codes = np.zeros((K, M, D), np.int8)
-    scales = np.zeros((K, M), np.float32)
-    for k in range(K):
-        codes[k], scales[k] = quantize_rows(keys[k])
-    cb = train_pq_codebook(keys.reshape(K * M, D), n_lists=4, n_sub=4,
-                           seed=0, iters=4)
-    index = build_ivfpq_index(cb, keys.reshape(K * M, D),
-                              valid.reshape(-1),
-                              np.repeat(np.arange(K, dtype=np.int32), M))
-
-    m = MetricsRegistry()
-    enable_profiling(m)
-    try:
-        federated_digest_lookup(jnp.asarray(q), jnp.asarray(keys),
-                                jnp.asarray(valid), 1)
-        federated_digest_lookup_quantized(jnp.asarray(q),
-                                          jnp.asarray(codes),
-                                          jnp.asarray(scales),
-                                          jnp.asarray(valid), 1)
-        federated_digest_lookup_ivfpq(jnp.asarray(q), index, 1, n_probe=2)
-    finally:
-        disable_profiling()
-
-    for op in ("federated_digest_lookup", "federated_digest_lookup_quantized",
-               "federated_digest_lookup_ivfpq"):
-        assert m.value(f"kernel/{op}/ref/calls") == 1, op
-        assert m.value(f"kernel/{op}/ref/modeled_bytes") > 0, op
-        assert m.value(f"kernel/{op}/ref/wall_ms")["count"] == 1, op
-    assert not any("/auto/" in n for n in m.names()), m.names()
-    # at board scale the IVF-PQ scan model beats the brute int8 row model
-    # >= 4x (at toy sizes the one-time shared codebook dominates, so the
-    # comparison is pinned on the models at 1M advertised rows)
+def test_ivf_pq_scan_model_beats_brute_int8_at_board_scale():
+    """At board scale the IVF-PQ scan model beats the brute int8 row model
+    >= 4x (at toy sizes the one-time shared codebook dominates, so the
+    comparison is pinned on the models at 1M advertised rows)."""
     from repro.obs.profile import digest_probe_bytes, ivf_pq_probe_bytes
     rows, L, S, Dm, nq, Km = 1_000_000, 1024, 8, 64, 64, 4
     ivf = ivf_pq_probe_bytes(nq, L, -(-rows // L), S, Dm)
