@@ -325,6 +325,13 @@ class ServingEngine:
         # in a device trace, so each operation says which layer it serves
         self._decode = jax.jit(model.decode_step, donate_argnums=(1,))
 
+        def argmax_lengths(logits, ln):
+            # a decode's next tokens and its new row lengths as one
+            # (2, B) array: the step reads both with a single transfer
+            return jnp.stack([jnp.argmax(logits, -1), ln])
+
+        self._argmax_lengths = jax.jit(argmax_lengths)
+
         def prefill(p, t, ln):
             return model.prefill(p, t, max_len=cfg.max_len, lengths=ln)
 
@@ -1115,7 +1122,10 @@ class ServingEngine:
             logits, self.cache, self.lengths = self._decode(
                 self.params, self.cache, self.tokens, self.lengths)
         self.dispatches["decode"] += 1
-        nxt = to_host(tr, "argmax", jnp.argmax(logits, -1))
+        # the lengths stay on the device; the done test reads the host
+        # copy that comes back with the argmax, once per step
+        nxt, lens = to_host(tr, "argmax",
+                            self._argmax_lengths(logits, self.lengths))
         self._decode_ms.observe((time.perf_counter() - t0) * 1e3)
         if tr.enabled:
             tr.end()
@@ -1125,8 +1135,7 @@ class ServingEngine:
             a.generated.append(int(nxt[slot]))
             done = (len(a.generated) >= self.cfg.max_new_tokens
                     or (self.cfg.eos_id >= 0 and nxt[slot] == self.cfg.eos_id)
-                    or int(to_host(tr, "length", self.lengths[slot]))
-                    >= self.cfg.max_len - 1)
+                    or lens[slot] >= self.cfg.max_len - 1)
             if done:
                 self._retire(slot)
         if tr.enabled:

@@ -7,6 +7,7 @@ A host read of a ``jax.Array`` (``int()``, ``np.asarray``,
 those reads during ``step()`` and hold the count to the ``d2h:*`` spans
 a recording ``Tracer`` holds.
 """
+import contextlib
 import re
 import time
 
@@ -25,11 +26,10 @@ from repro.serving.engine import ServingConfig, ServingEngine
 CASES = [(16, "always"), (16, "freq_weighted"), (0, "second_hit")]
 
 
-def _serve(model, params, *, kv_page, admission, tracer):
-    """Misses on node 0, then the same prompts on node 0 (local hits) and
-    node 1 (peer hits), twice.  Returns the engine, the host reads of
-    device values its steps made, and the wall time it took."""
-    reads = [0]
+@contextlib.contextmanager
+def _counting_reads(reads):
+    """Add one to ``reads[0]`` for each host read of a device array made
+    inside the block."""
     value = vars(ArrayImpl)["_value"]
 
     @property
@@ -37,6 +37,18 @@ def _serve(model, params, *, kv_page, admission, tracer):
         reads[0] += 1
         return value.fget(self)
 
+    ArrayImpl._value = counted
+    try:
+        yield
+    finally:
+        ArrayImpl._value = value
+
+
+def _serve(model, params, *, kv_page, admission, tracer):
+    """Misses on node 0, then the same prompts on node 0 (local hits) and
+    node 1 (peer hits), twice.  Returns the engine, the host reads of
+    device values its steps made, and the wall time it took."""
+    reads = [0]
     t0 = time.perf_counter()
     eng = ServingEngine(model, params, ServingConfig(
         max_batch=4, max_len=64, max_new_tokens=4, kv_page=kv_page,
@@ -51,11 +63,8 @@ def _serve(model, params, *, kv_page, admission, tracer):
         for p, node in zip(prompts, node_ids):
             eng.submit(p, node_id=node)
         while eng.pending or eng.queue or eng.chunking or eng.active:
-            ArrayImpl._value = counted
-            try:
+            with _counting_reads(reads):
                 eng.step()
-            finally:
-                ArrayImpl._value = value
     return eng, reads[0], time.perf_counter() - t0
 
 
@@ -87,8 +96,12 @@ def test_every_device_read_in_a_step_is_a_d2h_span(served, case):
     assert reads == len(d2h) > 0
     st = eng.stats()
     assert st["edge_hits"] > 0 and st["peer_hits"] > 0 and st["cloud"] > 0
-    # one read per active row per decode step, one argmax per decode
-    assert names.count("d2h:length") >= names.count("decode") > 0
+    # a decode reads its row lengths with its argmax, never row by row
+    assert "d2h:length" not in names
+    dispatched = (eng.dispatches["decode"] + eng.dispatches["prefill"]
+                  + eng.dispatches["prefill_chunk"])
+    assert 0 < names.count("decode") <= names.count("d2h:argmax") \
+        <= dispatched
     for what in ("descriptor", "argmax", "probe_idx", "probe_score", "hit",
                  "score", "value"):
         assert f"d2h:{what}" in d2h, what
@@ -117,12 +130,43 @@ def test_span_names_keep_the_reductions_vocabulary(served):
                     "probe:local", "probe:peer", "admit", "prefill_chunk",
                     "decode", "retire"}
     new = names - kept
-    assert {"emit", "chunk_prep", "d2h:length", "h2d:tokens",
+    assert {"emit", "chunk_prep", "h2d:tokens",
             "h2d:decode_table", "h2d:chunk", "h2d:row_state"} <= new
     for n in new:
         assert re.fullmatch(r"(d2h|h2d):\w+|emit|chunk_prep|request:\d+",
                             n), n
         assert n not in HOST_SPANS and not n.startswith("probe:"), n
+
+
+@pytest.mark.parametrize("kv_page", [16, 0])
+def test_a_decode_step_reads_once_however_many_rows_decode(tiny_model,
+                                                           kv_page):
+    """Once every row decodes, a step makes one host read, the argmax
+    with the row lengths, for one row as for four."""
+    model, params = tiny_model
+    rng = np.random.default_rng(1)
+    per_step = {}
+    for rows in (1, 4):
+        tracer = Tracer()
+        eng = ServingEngine(model, params, ServingConfig(
+            max_batch=4, max_len=64, max_new_tokens=8, kv_page=kv_page),
+            tracer=tracer)
+        for n in (8, 12, 24, 40)[:rows]:
+            eng.submit(rng.integers(0, model.cfg.vocab_size,
+                                    size=n).astype(np.int32))
+        while eng.pending or eng.queue or eng.chunking:
+            eng.step()
+        assert len(eng.active) == rows
+        reads = [0]
+        n_events = len(tracer.events)
+        with _counting_reads(reads):
+            eng.step()
+        assert len(eng.active) == rows
+        d2h = [e["name"] for e in tracer.events[n_events:]
+               if e.get("ph") == "B" and e["name"].startswith("d2h:")]
+        assert d2h == ["d2h:argmax"]
+        per_step[rows] = reads[0]
+    assert per_step[1] == per_step[4] == 1
 
 
 def test_hit_latency_is_measured_wall_time(served):

@@ -128,3 +128,30 @@ def test_overflow_truncate_serves_head_and_flags(served_model, nprng):
     by = {r.req_id: r for r in eng.results}
     assert by[r_long].truncated and not by[r_head].truncated
     np.testing.assert_array_equal(by[r_long].tokens, by[r_head].tokens)
+
+
+@pytest.mark.parametrize("kv_page", [0, 16])
+def test_row_retires_when_its_length_reaches_the_max_len_cap(
+        served_model, nprng, kv_page):
+    """A row stops at the step its length reaches ``max_len - 1``: a
+    ``max_len - 3`` prompt gives 3 tokens, whatever ``max_new_tokens``
+    asks, while a row 16 tokens shorter in the same batch runs to all
+    of them.  The tokens are the greedy ones."""
+    cfg, model, params = served_model
+    max_len, max_new = 64, 8
+    eng = ServingEngine(model, params, ServingConfig(
+        max_batch=2, max_len=max_len, max_new_tokens=max_new, eos_id=-1,
+        kv_page=kv_page))
+    capped = nprng.integers(0, cfg.vocab_size,
+                            size=(max_len - 3,)).astype(np.int32)
+    short = nprng.integers(0, cfg.vocab_size,
+                           size=(max_len - 3 - 16,)).astype(np.int32)
+    r_capped, r_short = eng.submit(capped), eng.submit(short)
+    eng.run_until_drained()
+    by = {r.req_id: r for r in eng.results}
+    assert len(by[r_capped].tokens) == 3
+    assert len(by[r_short].tokens) == max_new
+    np.testing.assert_array_equal(
+        by[r_capped].tokens, _greedy_reference(model, params, capped, 3))
+    np.testing.assert_array_equal(
+        by[r_short].tokens, _greedy_reference(model, params, short, max_new))
